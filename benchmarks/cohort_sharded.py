@@ -1,9 +1,7 @@
 """Cohort-parallel engine throughput: rounds/s vs devices on the client axis.
 
-Shards the cohort over an emulated ``("clients",)`` mesh (this module sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` BEFORE importing
-jax — run it as its own process, which is exactly how ``benchmarks.run``/
-CI invoke it) and measures ``FederatedEngine`` rounds/s at 1/2/4/8 mesh
+Shards the cohort over a ``("clients",)`` mesh of every visible device
+and measures ``FederatedEngine`` rounds/s at 1/2/4/8 mesh
 devices against the single-device flat+kernel baseline, sync
 (``run_rounds``) and async (``run_rounds_async``, D=2 — the ring gives the
 fold's reduce-scatter a round of compute to hide behind).
@@ -34,16 +32,17 @@ overlap ratio at the widest mesh.  ``benchmarks/fused_rounds.py`` folds
 this file (when present) into the top-level BENCH_fused_rounds.json
 trajectory summary.
 
-    PYTHONPATH=src python -m benchmarks.cohort_sharded [--rounds N]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.cohort_sharded [--rounds N]
+
+Run it as its own process: the device count is fixed when JAX starts
+(on a CPU host, by the ``XLA_FLAGS`` above, as CI sets it).
 """
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
 import json
+import os
 import time
 from pathlib import Path
 

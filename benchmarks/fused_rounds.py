@@ -84,7 +84,7 @@ from repro.models.small import classification_loss, mlp_classifier
 
 ARTIFACT = Path(__file__).resolve().parent / "artifacts" / "fused_rounds.json"
 #: the cohort-parallel sweep writes its own artifact (it needs a multi-
-#: device process: benchmarks/cohort_sharded.py sets XLA_FLAGS pre-import);
+#: device process of its own, started with XLA_FLAGS set on a CPU host);
 #: when present it is folded into the trajectory summary below
 COHORT_ARTIFACT = Path(__file__).resolve().parent / "artifacts" / "cohort_sharded.json"
 #: the participation scenario harness (host-store population engine) also

@@ -4,6 +4,12 @@ Runs one benchmark per paper table/figure plus the kernel accounting and —
 if dry-run artifacts exist — the roofline table.  ``--quick`` trims rounds
 and seeds for CI-speed runs; the full protocol (150 rounds × 2 seeds) is
 what EXPERIMENTS.md records.
+
+Everything runs in this one process, and a phase that fails raises, so
+the run exits non-zero.  The cohort-parallel sweep
+(``benchmarks.cohort_sharded``) is not a phase: it needs several devices
+from the moment JAX starts, so it runs as its own process (CI sets
+``XLA_FLAGS`` for it).
 """
 from __future__ import annotations
 
@@ -70,23 +76,6 @@ def main() -> int:
         # quick: fewer rounds AND fewer interleaved timing repetitions —
         # fused_rounds now measures two workloads (tree vs flat per each)
         fr(rounds=20 if args.quick else 60, alts=2 if args.quick else 8)
-
-    print("\n" + "=" * 72)
-    print("BENCHMARK 5b — cohort-parallel sweep (separate multi-device process)")
-    print("=" * 72)
-    if not args.skip_fed:
-        # cohort_sharded must own its process: XLA_FLAGS (8 emulated
-        # devices) has to be set before jax initializes, and this session's
-        # jax is already live.  Its artifact feeds the next fused_rounds
-        # trajectory row.
-        import subprocess
-
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.cohort_sharded",
-             "--rounds", "6" if args.quick else "20", "--alts", "2"],
-        )
-        if r.returncode != 0:
-            print("(cohort_sharded sweep failed — see output above)")
 
     print("\n" + "=" * 72)
     print("BENCHMARK 6/6 — roofline table (from dry-run artifacts)")

@@ -1,73 +1,53 @@
-"""FedCM over a transformer LM — the cross-silo production path.
+"""FedCM over a transformer LM — the cross-silo path, on a reduced config.
 
-Each "client" is an organization holding a corpus with its own token
-distribution (a distinct Markov chain = natural heterogeneity).  FedCM
-federates a reduced llama3-family model across them — the exact layer the
-multi-pod dry-run scales to datacenter federations (DESIGN.md §2.3).
+Each client is an organization holding a corpus with its own token
+distribution (its own Markov chain mixed with a shared one = natural
+heterogeneity).  FedCM federates a reduced llama3-family model across
+them through the engine's fused ``run_rounds`` scan, with the same data
+layout and loss adapter as the real-width client of ``chip_smoke.py``.
 
     PYTHONPATH=src python examples/federated_llm.py
 """
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs.base import FedConfig, get_config, reduced
 from repro.core import FederatedEngine
-from repro.data.synthetic import make_markov_transition, make_synthetic_lm
-from repro.models import build_model
+from repro.data import FederatedTokens, make_federated_lm_corpus
+from repro.models import build_model, federated_lm_loss
 
 N_CLIENTS = 8
 SEQ, BATCH = 64, 4
 
-cfg = reduced(get_config("llama3.2-1b"))
-model = build_model(cfg)
+
+def main(rounds: int = 20, echo: bool = True):
+    """Returns the per-round local losses."""
+    cfg = reduced(get_config("llama3.2-1b"))
+    model = build_model(cfg)
+    data = FederatedTokens.from_sequences(
+        make_federated_lm_corpus(cfg.vocab_size, N_CLIENTS, 256, SEQ + 1, seed=0)
+    )
+    cfg_fed = FedConfig(algo="fedcm", num_clients=N_CLIENTS, cohort_size=3,
+                        local_steps=4, alpha=0.1, eta_l=0.05, eta_g=1.0,
+                        weight_decay=1e-4, rounds=rounds)
+    eng = FederatedEngine(cfg_fed, federated_lm_loss(model), batch_size=BATCH)
+    state = eng.init(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
+    if echo:
+        print(f"federating {cfg.name} (~{cfg.param_count()/1e6:.1f}M params) "
+              f"across {N_CLIENTS} heterogeneous corpora with FedCM\n")
+    state, ms = eng.run_rounds(state, data, rounds)
+    losses = np.asarray(ms.loss)
+    if echo:
+        for r in range(4, rounds, 5):
+            print(f"round {r+1:3d}  local-loss={losses[r]:.4f}  "
+                  f"|Δ_t|={float(ms.momentum_norm[r]):.4f}  "
+                  f"active={int(ms.n_active[r])}")
+        print(f"\nloss {losses[0]:.3f} → {losses[-1]:.3f} "
+              f"(uniform {np.log(cfg.vocab_size):.3f})")
+    return losses
 
 
-def loss_fn(params, batch):
-    loss, _ = model.loss_fn(params, batch)
-    return loss
-
-
-# per-client corpora: shared base chain + per-client perturbation
-base = make_markov_transition(cfg.vocab_size, temperature=0.3, seed=0)
-client_tokens = []
-for c in range(N_CLIENTS):
-    pert = make_markov_transition(cfg.vocab_size, temperature=0.3, seed=100 + c)
-    trans = 0.6 * base + 0.4 * pert  # heterogeneous but related
-    client_tokens.append(make_synthetic_lm(cfg.vocab_size, SEQ + 1, 256, transition=trans, seed=c))
-client_tokens = np.stack(client_tokens)  # (N, n_seq, SEQ+1)
-
-
-class LMFedData:
-    """Minimal FederatedData-alike for LM batches."""
-
-    def __init__(self, toks):
-        self.toks = jnp.asarray(toks)
-        self.num_clients, self.n_per_client, _ = toks.shape
-
-    def sample_round_batches(self, rng, cohort_idx, K, B):
-        idx = jax.random.randint(rng, (cohort_idx.shape[0], K, B), 0, self.n_per_client)
-        seqs = self.toks[cohort_idx[:, None, None], idx]  # (C, K, B, SEQ+1)
-        return {"tokens": seqs[..., :-1], "labels": seqs[..., 1:]}
-
-
-cfg_fed = FedConfig(algo="fedcm", num_clients=N_CLIENTS, cohort_size=3,
-                    local_steps=4, alpha=0.1, eta_l=0.05, eta_g=1.0,
-                    weight_decay=1e-4, rounds=20)
-eng = FederatedEngine(cfg_fed, loss_fn, batch_size=BATCH)
-state = eng.init(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-data = LMFedData(client_tokens)
-
-print(f"federating {cfg.name} (~{cfg.param_count()/1e6:.1f}M params) "
-      f"across {N_CLIENTS} heterogeneous corpora with FedCM\n")
-first = None
-for r in range(cfg_fed.rounds):
-    state, m = eng.run_round(state, data)
-    if first is None:
-        first = float(m.loss)
-    if (r + 1) % 5 == 0:
-        print(f"round {r+1:3d}  local-loss={float(m.loss):.4f}  "
-              f"|Δ_t|={float(m.momentum_norm):.4f}  active={int(m.n_active)}")
-print(f"\nloss {first:.3f} → {float(m.loss):.3f} (uniform {np.log(cfg.vocab_size):.3f})")
-assert float(m.loss) < first
+if __name__ == "__main__":
+    losses = main()
+    assert losses[-1] < losses[0]

@@ -2,9 +2,10 @@ from repro.data.dirichlet import dirichlet_partition, label_distribution, hetero
 from repro.data.synthetic import (
     make_synthetic_classification,
     make_synthetic_images,
+    make_federated_lm_corpus,
     make_synthetic_lm,
 )
-from repro.data.pipeline import FederatedData, lm_batch_iterator
+from repro.data.pipeline import FederatedData, FederatedTokens, lm_batch_iterator
 from repro.data.population import (
     FaultyStore,
     HostPopulationStore,
@@ -20,8 +21,10 @@ __all__ = [
     "heterogeneity_score",
     "make_synthetic_classification",
     "make_synthetic_images",
+    "make_federated_lm_corpus",
     "make_synthetic_lm",
     "FederatedData",
+    "FederatedTokens",
     "lm_batch_iterator",
     "FaultyStore",
     "HostPopulationStore",

@@ -26,7 +26,7 @@ out-of-core ``HostPopulationStore`` engine path.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -101,6 +101,23 @@ class FederatedData:
         """Full local dataset for given clients (used by MimeLite's full-batch
         gradient at x_t)."""
         return gather_full_client_batch(self.client_x, self.client_y, client_ids)
+
+
+class FederatedTokens(NamedTuple):
+    """A federated LM corpus in the engine's layout: ``client_x`` holds the
+    (N, n_per_client, S) input tokens and ``client_y`` the same sequences
+    shifted by one (next-token labels).  ``gather_round_batches`` indexes
+    both as they are; ``repro.models.model.federated_lm_loss`` maps the
+    gathered ``{"x", "y"}`` to the model's ``{"tokens", "labels"}``."""
+
+    client_x: jax.Array
+    client_y: jax.Array
+
+    @classmethod
+    def from_sequences(cls, seqs) -> "FederatedTokens":
+        """``seqs`` (N, n_per_client, S + 1) int tokens."""
+        seqs = jnp.asarray(seqs, jnp.int32)
+        return cls(seqs[..., :-1], seqs[..., 1:])
 
 
 def lm_batch_iterator(

@@ -9,8 +9,10 @@ Three generators:
   frequency templates + noise; learnable by a small CNN.
 * ``make_synthetic_lm`` — token streams from a random first-order Markov
   chain (low-entropy rows), so next-token loss has real signal; used for the
-  centralized-LM example and the federated-LM example (clients get chains
-  with different transition matrices = natural heterogeneity).
+  centralized-LM example.
+* ``make_federated_lm_corpus`` — one sparse Markov corpus per client over
+  a real-size vocabulary (clients mix a shared chain with their own =
+  natural heterogeneity); the federated LM client's data.
 """
 from __future__ import annotations
 
@@ -109,6 +111,49 @@ def make_synthetic_lm(
         state = (cdf[state] < u[:, None]).sum(axis=1)
         state = np.minimum(state, vocab_size - 1)
         toks[:, t] = state
+    return toks
+
+
+def make_federated_lm_corpus(
+    vocab_size: int,
+    n_clients: int,
+    n_seqs: int,
+    seq_len: int,
+    *,
+    branching: int = 8,
+    shared: float = 0.6,
+    zipf: float = 1.1,
+    seed: int = 0,
+) -> np.ndarray:
+    """(n_clients, n_seqs, seq_len) int32 tokens, one Markov corpus per client.
+
+    Every token has ``branching`` equally likely successors, drawn with
+    Zipf(``zipf``) token frequencies as in text.  Each step follows a chain
+    shared by all clients with probability ``shared`` and the client's own
+    chain otherwise; each client ranks the vocabulary in its own order, so
+    the corpora are heterogeneous but related.  The chains are
+    ``(V, branching)`` successor tables, so a real-size vocabulary costs
+    O(V·branching), not the O(V²) of a dense transition matrix.
+    """
+    rng = np.random.default_rng(seed)
+    freq = 1.0 / np.arange(1, vocab_size + 1) ** zipf
+    freq /= freq.sum()
+
+    def successors(ranking):
+        return ranking[rng.choice(vocab_size, size=(vocab_size, branching), p=freq)]
+
+    base = successors(np.arange(vocab_size))
+    own = np.stack([successors(rng.permutation(vocab_size))
+                    for _ in range(n_clients)])
+    client = np.arange(n_clients)[:, None]
+    toks = np.empty((n_clients, n_seqs, seq_len), dtype=np.int32)
+    state = rng.integers(0, vocab_size, size=(n_clients, n_seqs))
+    toks[..., 0] = state
+    for t in range(1, seq_len):
+        pick = rng.integers(0, branching, size=state.shape)
+        from_base = rng.random(state.shape) < shared
+        state = np.where(from_base, base[state, pick], own[client, state, pick])
+        toks[..., t] = state
     return toks
 
 

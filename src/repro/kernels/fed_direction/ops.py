@@ -27,14 +27,10 @@ no collective ever enters the local-step loop.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.fed_direction.kernel import fed_direction_flat
-
-# CPU container: interpret mode (executes the kernel body in python).
-# On a real TPU runtime set INTERPRET=False.
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def _coefs(eta_l, c_g, c_x, *c_aux):
@@ -75,4 +71,4 @@ def flat_direction_step(algo, cfg, x, g, m, cst, x0, eta_l):
         auxes.append(x0)
         aux_coefs.append(-c_x)
     coefs = _coefs(eta_l, c_g, c_x, *aux_coefs)
-    return fed_direction_flat(x, g, tuple(auxes), coefs, interpret=INTERPRET)
+    return fed_direction_flat(x, g, tuple(auxes), coefs, interpret=kernels.interpret_mode())

@@ -3,12 +3,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
-
-INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(
@@ -28,6 +26,6 @@ def flash_attention(
     vt = jnp.swapaxes(v, 1, 2)
     out = flash_attention_bhsd(
         qt, kt, vt, causal=causal, window=window, scale=scale,
-        q_offset=q_offset, bq=bq, bkv=bkv, interpret=INTERPRET,
+        q_offset=q_offset, bq=bq, bkv=bkv, interpret=kernels.interpret_mode(),
     )
     return jnp.swapaxes(out, 1, 2)

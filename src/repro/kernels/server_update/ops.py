@@ -29,25 +29,29 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.kernels.server_update.kernel import (
     DEFAULT_BLOCK, LANE, dequant_update_flat, server_update_flat,
 )
 
-# CPU container: interpret mode (executes the kernel body in python).
-# On a real TPU runtime set INTERPRET=False.
-INTERPRET = jax.default_backend() != "tpu"
+
+# Mosaic tiles a block's last two dims in (sublanes, LANE) units: 8
+# sublanes for f32, 16 for bf16, 32 for int8.  A block of whole int8 tiles
+# is whole tiles of the wider dtypes too.
+TILE = 32 * LANE
 
 
 def _auto_block(n: int, default: int = DEFAULT_BLOCK) -> int:
-    """Largest LANE-multiple block ≤ ``default`` giving a ≥ 2-step grid.
+    """Largest TILE-multiple block ≤ ``default`` giving a ≥ 2-step grid.
 
     Keeps the interpret-mode grid loop a REAL loop for every plane length
-    that allows it (n > 2·LANE): the loop body then compiles as its own
+    that allows it (n ≥ 2·TILE): the loop body then compiles as its own
     shape-stable computation, and sharded / unsharded launches of the same
-    fold stay bitwise (see module docstring).  Sub-2·LANE planes keep the
-    single block — there is nothing to split."""
-    half = (n // (2 * LANE)) * LANE
-    return max(LANE, min(default, half)) if half else min(default, LANE)
+    fold stay bitwise (see module docstring).  Shorter planes take one
+    TILE (the launch's grid floor of 2 pads them).  A block that is not
+    whole tiles compiles in interpret mode but Mosaic refuses it."""
+    half = (n // (2 * TILE)) * TILE
+    return max(TILE, min(default, half))
 
 
 def fused_server_step(deltas, wn, x, m, c_mm, c_md, c_xd, m_dtype=None,
@@ -74,7 +78,7 @@ def fused_server_step(deltas, wn, x, m, c_mm, c_md, c_xd, m_dtype=None,
         jnp.asarray(discount, jnp.float32),
     ])
     return server_update_flat(
-        deltas, wn, x, m, coefs, m_dtype=m_dtype, interpret=INTERPRET,
+        deltas, wn, x, m, coefs, m_dtype=m_dtype, interpret=kernels.interpret_mode(),
         block_elems=_auto_block(deltas.shape[-1]),
         write_x=write_x, write_m=write_m,
     )
@@ -94,7 +98,7 @@ def dequant_server_step(q, scale, wn, x, m, c_mm, c_md, c_xd, m_dtype=None,
         jnp.asarray(discount, jnp.float32),
     ])
     return dequant_update_flat(
-        q, scale, wn, x, m, coefs, m_dtype=m_dtype, interpret=INTERPRET,
+        q, scale, wn, x, m, coefs, m_dtype=m_dtype, interpret=kernels.interpret_mode(),
         block_elems=_auto_block(q.shape[-1]),
         write_x=write_x, write_m=write_m,
     )

@@ -45,6 +45,7 @@ import os
 from pathlib import Path
 
 import time
+from typing import Any, NamedTuple, Tuple
 
 import numpy as np
 
@@ -66,12 +67,55 @@ from repro.fleet.telemetry import FAULT_COUNTERS, ROUND_FIELDS, TELEMETRY_SCHEMA
 from repro.data import FederatedData, StreamingClientData, make_synthetic_classification
 from repro.data.population import AVAILABILITY_PROCESSES, POPULATION_STORES
 from repro.models.small import classification_loss, mlp_classifier
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.metrics import MetricLogger
 
 DRYRUN_ARTIFACT = (
     Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts"
     / "fed_train_dryrun.json"
 )
+
+
+class ClassificationRun(NamedTuple):
+    """What a classification run starts from (``build_run``)."""
+
+    data: Any  # FederatedData or StreamingClientData
+    model: Any
+    engine: FederatedEngine
+    state: Any  # FedState
+    test: Tuple[Any, Any]  # (x_test, y_test) as host arrays
+
+
+def build_run(
+    cfg: FedConfig,
+    dirichlet: float,
+    *,
+    dim: int = 32,
+    n_classes: int = 10,
+    n_train: int = 50_000,
+    n_test: int = 10_000,
+    batch_size: int = 50,
+    hidden: int = 128,
+    seed: int = 0,
+) -> ClassificationRun:
+    """Data, MLP classifier, engine and initial state of a run."""
+    if cfg.population_store == "host":
+        # out-of-core path: no (N, n_per, …) device stack exists — shards
+        # regenerate on demand per sampled cohort (label skew replaces the
+        # Dirichlet partition; --dirichlet is a no-op here)
+        data = StreamingClientData(cfg.num_clients, dim=dim,
+                                   n_classes=n_classes, seed=seed)
+        test = data.test_set(min(n_test, 2_000))
+    else:
+        x_tr, y_tr, x_te, y_te = make_synthetic_classification(
+            n_classes=n_classes, dim=dim, n_train=n_train, n_test=n_test, seed=seed
+        )
+        data = FederatedData(x_tr, y_tr, cfg.num_clients, dirichlet_alpha=dirichlet, seed=seed)
+        test = (x_te, y_te)
+    model = mlp_classifier((dim, hidden, hidden, n_classes))
+    eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=batch_size)
+    state = eng.init(model.init(jax.random.PRNGKey(seed)), jax.random.PRNGKey(seed + 1))
+    return ClassificationRun(data, model, eng, state, test)
 
 
 def run_federated(
@@ -116,23 +160,11 @@ def run_federated(
     decode steps), a health endpoint reports round liveness, and an
     append-only telemetry JSONL records one row per round.  Fleet is
     observation-only — the training trajectory is bit-identical with or
-    without it."""
-    if cfg.population_store == "host":
-        # out-of-core path: no (N, n_per, …) device stack exists — shards
-        # regenerate on demand per sampled cohort (label skew replaces the
-        # Dirichlet partition; --dirichlet is a no-op here)
-        data = StreamingClientData(cfg.num_clients, dim=dim,
-                                   n_classes=n_classes, seed=seed)
-        x_te, y_te = data.test_set(min(n_test, 2_000))
-    else:
-        x_tr, y_tr, x_te, y_te = make_synthetic_classification(
-            n_classes=n_classes, dim=dim, n_train=n_train, n_test=n_test, seed=seed
-        )
-        data = FederatedData(x_tr, y_tr, cfg.num_clients, dirichlet_alpha=dirichlet, seed=seed)
-    model = mlp_classifier((dim, hidden, hidden, n_classes))
-    loss_fn = classification_loss(model.apply)
-    eng = FederatedEngine(cfg, loss_fn, batch_size=batch_size)
-    state = eng.init(model.init(jax.random.PRNGKey(seed)), jax.random.PRNGKey(seed + 1))
+    without it.  The logged loss is the chunk's last round, unrounded."""
+    data, model, eng, state, (x_te, y_te) = build_run(
+        cfg, dirichlet, dim=dim, n_classes=n_classes, n_train=n_train,
+        n_test=n_test, batch_size=batch_size, hidden=hidden, seed=seed,
+    )
     evaluate = make_eval_fn(model.apply)
 
     log = MetricLogger(
@@ -260,7 +292,7 @@ def run_federated(
                 fleet.record_chunk(start_round=r - chunk, host=host,
                                    seconds=dt, eval_acc=acc,
                                    published_version=pub_version)
-            log.log(round=r, algo=cfg.algo, loss=round(float(host["loss"][-1]), 4),
+            log.log(round=r, algo=cfg.algo, loss=float(host["loss"][-1]),
                     test_acc=round(acc, 4), n_active=int(host["n_active"][-1]),
                     mb_down=round(float(host["bytes_down"][-1]) / 2**20, 2),
                     mb_up=round(float(host["bytes_up"][-1]) / 2**20, 2),
@@ -688,6 +720,7 @@ def main(argv=None) -> int:
         ap.error("--publish-retain must be >= 2: the publication ring must "
                  "outlive a reader's just-resolved version")
     cfg = resolve_config(args)
+    use_compile_cache()
     if args.dryrun:
         path = write_dryrun_artifact(cfg, args)
         print(f"dryrun: resolved config written to {path}")

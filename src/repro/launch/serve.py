@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.configs.base import ARCH_IDS, get_config, reduced
 from repro.data.synthetic import make_synthetic_lm
 from repro.models import build_model
+from repro.utils.compile_cache import use_compile_cache
 
 
 @dataclass
@@ -196,6 +197,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.follow and not args.ckpt:
         ap.error("--follow watches the --ckpt directory — add --ckpt DIR")
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

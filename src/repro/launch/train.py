@@ -26,6 +26,7 @@ from repro.launch.mesh import make_test_mesh
 from repro.launch.steps import Knobs, build_train_step
 from repro.models import build_model
 from repro.optim.optimizers import warmup_cosine
+from repro.utils.compile_cache import use_compile_cache
 from repro.utils.metrics import MetricLogger
 
 
@@ -43,6 +44,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

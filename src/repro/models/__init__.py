@@ -1,3 +1,3 @@
-from repro.models.model import build_model, Model
+from repro.models.model import build_model, federated_lm_loss, Model
 
-__all__ = ["build_model", "Model"]
+__all__ = ["build_model", "federated_lm_loss", "Model"]

@@ -58,6 +58,17 @@ class Model(NamedTuple):
     input_specs: Callable[[ShapeConfig], Dict[str, Any]]
 
 
+def federated_lm_loss(model: Model) -> Callable[[Any, Dict[str, Any]], jax.Array]:
+    """The federated engine's ``loss_fn`` for an LM client: the engine
+    gathers ``{"x", "y"}`` minibatches (``repro.data.FederatedTokens``), the
+    model reads ``{"tokens", "labels"}``."""
+
+    def loss(params, batch):
+        return model.loss_fn(params, {"tokens": batch["x"], "labels": batch["y"]})[0]
+
+    return loss
+
+
 def build_model(cfg: ModelConfig) -> Model:
     if cfg.is_encoder_decoder:
         return _build_encdec(cfg)

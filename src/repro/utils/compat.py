@@ -1,37 +1,27 @@
-"""jax version-compatibility shims.
+"""The one routing point for the jax mesh and ``shard_map`` APIs.
 
-Compat policy (this repo pins nothing; the container pins jax): the code
-is written against the *current* public jax API (``jax.set_mesh``,
-``jax.shard_map``), and every call site that drifted across jax releases
-goes through this module instead of jax directly.  Each shim resolves the
-right symbol for the installed jax at call time:
+The repo is written against the installed jax (0.9.0).  Every mesh
+constructor, ambient-mesh context and ``shard_map`` call goes through this
+module instead of jax directly, so the next API move lands in one place:
 
-* ``set_mesh(mesh)`` — context manager making ``mesh`` the ambient mesh.
-  jax >= 0.5 exposes ``jax.set_mesh``; on 0.4.x a ``jax.sharding.Mesh`` is
-  itself a context manager, so the mesh object is returned directly.
+* ``set_mesh(mesh)`` — ``jax.set_mesh``: context manager making ``mesh``
+  the ambient mesh.
+* ``make_mesh(axis_shapes, axis_names)`` — ``jax.make_mesh`` with every
+  axis ``AxisType.Auto``.  jax's default is ``Explicit`` axes, under which
+  the models' ``with_sharding_constraint`` calls are refused ("can only
+  refer to Auto axes").
+* ``device_mesh(devices, axis_names)`` — the explicit-device-list ``Mesh``
+  constructor.
 * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)`` —
-  newer jax has top-level ``jax.shard_map`` with the ``check_vma`` kwarg;
-  0.4.x has ``jax.experimental.shard_map.shard_map`` where the same knob
-  is spelled ``check_rep``.
-* ``make_mesh(axis_shapes, axis_names)`` — ``jax.make_mesh`` (new in
-  0.4.35, device-order-aware) when present, else the
-  ``mesh_utils.create_device_mesh`` + ``Mesh`` spelling.
-* ``device_mesh(devices, axis_names)`` — the explicit-device-list
-  ``Mesh`` constructor.  The class moved homes across releases
-  (``jax.sharding.Mesh`` today, ``jax.interpreters.pxla`` before);
-  constructing through here keeps call sites home-agnostic.
+  ``jax.shard_map``.
 
-Resolution happens per call (cheap ``hasattr``), not at import, so tests
-can exercise both paths by monkeypatching the ``jax`` module.  New code
-should import from here rather than hand-rolling version checks — the
-REP002 lint rule (``repro.analysis.lint``) enforces exactly that: any
+The REP002 lint rule (``repro.analysis.lint``) enforces the routing: any
 direct call to the symbols above outside this module is a finding.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
 
 def set_mesh(mesh):
@@ -42,25 +32,15 @@ def set_mesh(mesh):
         with set_mesh(mesh):
             compiled = fn.lower(...).compile()
     """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    # jax 0.4.x: Mesh implements the context-manager protocol itself.
-    return mesh
+    return jax.set_mesh(mesh)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """Version-portable ``jax.make_mesh``.
-
-    Prefers ``jax.make_mesh`` (picks a device order that favors the
-    backend's collective topology); older jax falls back to
-    ``mesh_utils.create_device_mesh`` with the default device list.
-    """
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    from jax.experimental import mesh_utils
-    from jax.sharding import Mesh
-
-    return Mesh(mesh_utils.create_device_mesh(axis_shapes), axis_names)
+    """``jax.make_mesh`` (device order that favors the backend's collective
+    topology) with ``Auto`` axes, so sharding constraints may name them."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
+    )
 
 
 def device_mesh(devices, axis_names):
@@ -78,33 +58,9 @@ def device_mesh(devices, axis_names):
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Version-portable ``shard_map``.
-
-    Accepts the modern keyword ``check_vma``; where the resolved function
-    still spells it ``check_rep`` (0.4.x experimental, and the promotion
-    window where ``jax.shard_map`` exists but predates the rename) it is
-    translated.  The kwarg spelling is detected from the resolved
-    function's own signature — the two API changes (promotion out of
-    experimental, check_rep→check_vma rename) landed in different jax
-    releases, so one must not be inferred from the other.  All other
-    kwargs pass through untouched.
-    """
-    toplevel = hasattr(jax, "shard_map")
-    if toplevel:
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default.  All other
+    kwargs pass through untouched."""
     if check_vma is not None:
-        try:
-            params = inspect.signature(fn).parameters
-        except (TypeError, ValueError):  # C-level / wrapped callables
-            params = {}
-        if "check_vma" in params:
-            key = "check_vma"
-        elif "check_rep" in params:
-            key = "check_rep"
-        else:  # **kwargs-only signature: fall back on the symbol's home
-            key = "check_vma" if toplevel else "check_rep"
-        kwargs[key] = check_vma
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         **kwargs)

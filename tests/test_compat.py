@@ -1,9 +1,8 @@
-"""Regression tests for the jax version-compat shims (repro.utils.compat).
+"""Tests for the jax routing shims (repro.utils.compat).
 
-Both resolution paths are covered: the real installed-jax path (executed),
-and the "newer jax" path (simulated by monkeypatching top-level ``jax``
-attributes — the shims resolve per call, so this exercises the dispatch
-logic without needing a second jax install).
+The shims run for real on the installed jax, and monkeypatched top-level
+``jax`` attributes check that each one forwards to the jax symbol it
+routes (resolved per call, so a patch is seen).
 """
 import numpy as np
 import pytest
@@ -20,7 +19,7 @@ def _one_device_mesh():
 
 
 # ----------------------------------------------------------------------
-# installed-jax path (whatever this container has)
+# executed on the installed jax
 # ----------------------------------------------------------------------
 
 
@@ -58,8 +57,23 @@ def test_shard_map_psum_value():
     assert float(out[0]) == pytest.approx(6.0)
 
 
+def test_make_mesh_axes_are_auto():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
+
+
+def test_make_mesh_accepts_sharding_constraints():
+    """Explicit axes (jax.make_mesh's default) refuse
+    ``with_sharding_constraint``; every model layer relies on it."""
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    sh = jax.sharding.NamedSharding(mesh, P("data", "model"))
+    f = jax.jit(lambda x: jax.lax.with_sharding_constraint(x * 2, sh))
+    np.testing.assert_array_equal(np.asarray(f(jnp.ones((2, 2)))),
+                                  2 * np.ones((2, 2)))
+
+
 # ----------------------------------------------------------------------
-# newer-jax path (simulated: top-level jax.set_mesh / jax.shard_map exist)
+# forwarding (monkeypatched top-level jax.set_mesh / jax.shard_map)
 # ----------------------------------------------------------------------
 
 
@@ -77,13 +91,6 @@ def test_set_mesh_prefers_toplevel_api(monkeypatch):
     assert calls == [mesh]
 
 
-def test_set_mesh_falls_back_to_mesh_context(monkeypatch):
-    monkeypatch.delattr(jax, "set_mesh", raising=False)
-    mesh = _one_device_mesh()
-    # 0.4.x path: the Mesh object itself is the context manager
-    assert compat.set_mesh(mesh) is mesh
-
-
 def test_shard_map_prefers_toplevel_api_and_passes_check_vma(monkeypatch):
     seen = {}
 
@@ -99,43 +106,3 @@ def test_shard_map_prefers_toplevel_api_and_passes_check_vma(monkeypatch):
     assert f(jnp.zeros(())) == "new-path"
     assert seen["check_vma"] is False
     assert seen["mesh"] is mesh
-
-
-def test_shard_map_old_path_translates_check_vma_to_check_rep(monkeypatch):
-    """Dispatch check: without jax.shard_map, the experimental symbol is used
-    and ``check_vma`` is respelled ``check_rep``.  (A fake stands in for the
-    experimental function — the real one re-enters its own module-global
-    name internally, so wrapping it would intercept internal calls too.)"""
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    import jax.experimental.shard_map as sm
-
-    seen = {}
-
-    def fake(f, *, mesh, in_specs, out_specs, **kwargs):
-        seen.update(kwargs, mesh=mesh)
-        return lambda *a: "old-path"
-
-    monkeypatch.setattr(sm, "shard_map", fake)
-    mesh = _one_device_mesh()
-    f = compat.shard_map(
-        lambda x: x * 2, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-        check_vma=False,
-    )
-    assert f(jnp.ones((4,))) == "old-path"
-    assert seen["check_rep"] is False
-    assert "check_vma" not in seen
-    assert seen["mesh"] is mesh
-
-
-def test_shard_map_old_path_executes_for_real():
-    """End-to-end on the installed 0.4.x jax: the translated check_rep path
-    actually runs (this is what models/layers.py depends on)."""
-    if hasattr(jax, "shard_map"):
-        pytest.skip("installed jax has top-level shard_map; old path unreachable")
-    mesh = _one_device_mesh()
-    f = compat.shard_map(
-        lambda x: x * 2, mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
-        check_vma=False,
-    )
-    out = f(jnp.ones((4,)))
-    np.testing.assert_array_equal(np.asarray(out), 2 * np.ones((4,)))

@@ -264,3 +264,26 @@ def test_dryrun_artifact_static_contracts(tmp_path, monkeypatch):
     sc = json.loads(art.read_text())["static_contracts"]
     assert sc["donation_ok"] is True
     assert "async" in sc["path"]
+
+
+@pytest.mark.parametrize("env_dir", ["", "/somewhere/else"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The env var wins and is left to jax; otherwise the fixed repo path."""
+    import jax
+
+    from repro.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV, env_dir)
+    try:
+        got = compile_cache.use_compile_cache()
+        if env_dir:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(compile_cache.REPO_CACHE_DIR)
+            assert compile_cache.REPO_CACHE_DIR.name == ".jax_cache"
+            assert (compile_cache.REPO_CACHE_DIR.parent / "src" / "repro").is_dir()
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -387,3 +387,38 @@ def test_ssd_kernel_vs_model_chunked():
     y_m, st_m = ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_m), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(st_k), np.asarray(st_m), rtol=2e-4, atol=2e-4)
+
+
+# ----------------------------------------------------------------------
+# interpret-vs-Mosaic choice: made per launch, never at import
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("platform,expected", [("cpu", True), ("tpu", False)])
+def test_interpret_mode_follows_backend(monkeypatch, platform, expected):
+    from repro import kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert kernels.interpret_mode() is expected
+
+
+def test_interpret_mode_refuses_other_platforms(monkeypatch):
+    from repro import kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        kernels.interpret_mode()
+
+
+def test_importing_the_engine_starts_no_backend():
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro.core, repro.launch.fed_train\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
