@@ -775,6 +775,11 @@ class FederatedEngine:
                     second_moment=spec.ravel(sm) if sm is not None else None,
                     client_states=cst,
                 ))
+        if self.cohort_mesh is not None:
+            # the sharded round returns its state replicated over the
+            # cohort mesh: start it there, so the next call does not
+            # retrace and recompile for a new input sharding
+            state = jax.device_put(state, NamedSharding(self.cohort_mesh, P()))
         return state
 
     @staticmethod

@@ -114,33 +114,37 @@ def make_synthetic_lm(
     return toks
 
 
+# make_federated_lm_corpus: successors per token, probability of a step
+# along the chain all clients share, Zipf exponent of token frequencies
+LM_BRANCHING = 8
+LM_SHARED = 0.6
+LM_ZIPF = 1.1
+
+
 def make_federated_lm_corpus(
     vocab_size: int,
     n_clients: int,
     n_seqs: int,
     seq_len: int,
     *,
-    branching: int = 8,
-    shared: float = 0.6,
-    zipf: float = 1.1,
     seed: int = 0,
 ) -> np.ndarray:
     """(n_clients, n_seqs, seq_len) int32 tokens, one Markov corpus per client.
 
-    Every token has ``branching`` equally likely successors, drawn with
-    Zipf(``zipf``) token frequencies as in text.  Each step follows a chain
-    shared by all clients with probability ``shared`` and the client's own
-    chain otherwise; each client ranks the vocabulary in its own order, so
-    the corpora are heterogeneous but related.  The chains are
-    ``(V, branching)`` successor tables, so a real-size vocabulary costs
-    O(V·branching), not the O(V²) of a dense transition matrix.
+    Every token has ``LM_BRANCHING`` equally likely successors, drawn with
+    Zipf(``LM_ZIPF``) token frequencies as in text.  Each step follows a
+    chain shared by all clients with probability ``LM_SHARED`` and the
+    client's own chain otherwise; each client ranks the vocabulary in its
+    own order, so the corpora are heterogeneous but related.  The chains
+    are ``(V, LM_BRANCHING)`` successor tables, so a real-size vocabulary
+    costs O(V·LM_BRANCHING), not the O(V²) of a dense transition matrix.
     """
     rng = np.random.default_rng(seed)
-    freq = 1.0 / np.arange(1, vocab_size + 1) ** zipf
+    freq = 1.0 / np.arange(1, vocab_size + 1) ** LM_ZIPF
     freq /= freq.sum()
 
     def successors(ranking):
-        return ranking[rng.choice(vocab_size, size=(vocab_size, branching), p=freq)]
+        return ranking[rng.choice(vocab_size, size=(vocab_size, LM_BRANCHING), p=freq)]
 
     base = successors(np.arange(vocab_size))
     own = np.stack([successors(rng.permutation(vocab_size))
@@ -150,8 +154,8 @@ def make_federated_lm_corpus(
     state = rng.integers(0, vocab_size, size=(n_clients, n_seqs))
     toks[..., 0] = state
     for t in range(1, seq_len):
-        pick = rng.integers(0, branching, size=state.shape)
-        from_base = rng.random(state.shape) < shared
+        pick = rng.integers(0, LM_BRANCHING, size=state.shape)
+        from_base = rng.random(state.shape) < LM_SHARED
         state = np.where(from_base, base[state, pick], own[client, state, pick])
         toks[..., t] = state
     return toks

@@ -45,7 +45,6 @@ import os
 from pathlib import Path
 
 import time
-from typing import Any, NamedTuple, Tuple
 
 import numpy as np
 
@@ -74,48 +73,6 @@ DRYRUN_ARTIFACT = (
     Path(__file__).resolve().parents[3] / "benchmarks" / "artifacts"
     / "fed_train_dryrun.json"
 )
-
-
-class ClassificationRun(NamedTuple):
-    """What a classification run starts from (``build_run``)."""
-
-    data: Any  # FederatedData or StreamingClientData
-    model: Any
-    engine: FederatedEngine
-    state: Any  # FedState
-    test: Tuple[Any, Any]  # (x_test, y_test) as host arrays
-
-
-def build_run(
-    cfg: FedConfig,
-    dirichlet: float,
-    *,
-    dim: int = 32,
-    n_classes: int = 10,
-    n_train: int = 50_000,
-    n_test: int = 10_000,
-    batch_size: int = 50,
-    hidden: int = 128,
-    seed: int = 0,
-) -> ClassificationRun:
-    """Data, MLP classifier, engine and initial state of a run."""
-    if cfg.population_store == "host":
-        # out-of-core path: no (N, n_per, …) device stack exists — shards
-        # regenerate on demand per sampled cohort (label skew replaces the
-        # Dirichlet partition; --dirichlet is a no-op here)
-        data = StreamingClientData(cfg.num_clients, dim=dim,
-                                   n_classes=n_classes, seed=seed)
-        test = data.test_set(min(n_test, 2_000))
-    else:
-        x_tr, y_tr, x_te, y_te = make_synthetic_classification(
-            n_classes=n_classes, dim=dim, n_train=n_train, n_test=n_test, seed=seed
-        )
-        data = FederatedData(x_tr, y_tr, cfg.num_clients, dirichlet_alpha=dirichlet, seed=seed)
-        test = (x_te, y_te)
-    model = mlp_classifier((dim, hidden, hidden, n_classes))
-    eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=batch_size)
-    state = eng.init(model.init(jax.random.PRNGKey(seed)), jax.random.PRNGKey(seed + 1))
-    return ClassificationRun(data, model, eng, state, test)
 
 
 def run_federated(
@@ -160,16 +117,30 @@ def run_federated(
     decode steps), a health endpoint reports round liveness, and an
     append-only telemetry JSONL records one row per round.  Fleet is
     observation-only — the training trajectory is bit-identical with or
-    without it.  The logged loss is the chunk's last round, unrounded."""
-    data, model, eng, state, (x_te, y_te) = build_run(
-        cfg, dirichlet, dim=dim, n_classes=n_classes, n_train=n_train,
-        n_test=n_test, batch_size=batch_size, hidden=hidden, seed=seed,
-    )
+    without it.  The logged loss is the chunk's last round, unrounded;
+    ``seconds`` is the chunk's wall time up to its metrics on the host
+    (the first chunk includes tracing and compilation)."""
+    if cfg.population_store == "host":
+        # out-of-core path: no (N, n_per, …) device stack exists — shards
+        # regenerate on demand per sampled cohort (label skew replaces the
+        # Dirichlet partition; --dirichlet is a no-op here)
+        data = StreamingClientData(cfg.num_clients, dim=dim,
+                                   n_classes=n_classes, seed=seed)
+        x_te, y_te = data.test_set(min(n_test, 2_000))
+    else:
+        x_tr, y_tr, x_te, y_te = make_synthetic_classification(
+            n_classes=n_classes, dim=dim, n_train=n_train, n_test=n_test, seed=seed
+        )
+        data = FederatedData(x_tr, y_tr, cfg.num_clients, dirichlet_alpha=dirichlet, seed=seed)
+    model = mlp_classifier((dim, hidden, hidden, n_classes))
+    loss_fn = classification_loss(model.apply)
+    eng = FederatedEngine(cfg, loss_fn, batch_size=batch_size)
+    state = eng.init(model.init(jax.random.PRNGKey(seed)), jax.random.PRNGKey(seed + 1))
     evaluate = make_eval_fn(model.apply)
 
     log = MetricLogger(
         ["round", "algo", "loss", "test_acc", "n_active", "mb_down", "mb_up",
-         "dropped", "quar", "retries", "qskip"],
+         "dropped", "quar", "retries", "qskip", "seconds"],
         echo=echo, echo_every=1,
     )
     x_te_j, y_te_j = jnp.asarray(x_te), jnp.asarray(y_te)
@@ -299,7 +270,8 @@ def run_federated(
                     dropped=int(host["n_dropped"].sum()) if "n_dropped" in host else None,
                     quar=int(host["n_quarantined"].sum()) if "n_quarantined" in host else None,
                     retries=int(host["n_retries"].sum()) if "n_retries" in host else None,
-                    qskip=int(host["quorum_skipped"].sum()) if "quorum_skipped" in host else None)
+                    qskip=int(host["quorum_skipped"].sum()) if "quorum_skipped" in host else None,
+                    seconds=dt)
             if snapshot and die_after > 0 and r >= die_after:
                 # simulate preemption: no cleanup, no atexit — the
                 # snapshot just published is all a resume may rely on

@@ -152,6 +152,16 @@ def test_cfg_cohort_shard_builds_mesh():
     assert int(state.server.round) == 2
 
 
+def test_sharded_state_reuses_the_compiled_round():
+    """``init`` places the state on the cohort mesh as the sharded round
+    returns it, so chunked calls (``fed_train``'s eval cadence) trace and
+    compile the round once, not again for the second chunk's layout."""
+    eng, state = _engine("fedcm", 1)
+    for _ in range(3):
+        state, _ = eng.run_rounds(state, _data(32), 2)
+    assert eng.run_rounds_traces == 1
+
+
 # ----------------------------------------------------------------------
 # single-shard mesh ≡ unsharded — runs everywhere, tier-1 included
 # ----------------------------------------------------------------------
