@@ -79,12 +79,12 @@ cohort means concatenate per-leaf contractions into ONE ``(P,)`` buffer,
 the server update and metric norms are single fused ops, and stateless
 algorithms never materialize the zero state/extra planes the tree path
 builds and aggregates.  The K-step local scan itself keeps the LEAF form
-(model autodiff is per-leaf; a flat↔tree conversion per step measures
-2-3× slower on CPU XLA), so its body is bitwise the tree path's.  Under
-``use_fused_kernel`` the scan flips to the flat ``(P,)`` carry — the
-kernels consume flat buffers directly, per-client control variates ride an
-``(N, P)`` plane (ONE gather/scatter), and the per-step concatenate/split
-of the PR-1 kernel route disappears entirely.  The tree path
+(model autodiff is per-leaf; a flat↔tree conversion per step would
+concatenate and split the whole plane), so its body is bitwise the tree
+path's.  Under ``use_fused_kernel`` the scan flips to the flat ``(P,)``
+carry — the kernels consume flat buffers directly, per-client control
+variates ride an ``(N, P)`` plane (ONE gather/scatter), and a tree-form
+kernel's per-step concatenate/split is gone.  The tree path
 (``use_flat_plane=False``) is retained verbatim as the numerical oracle
 (tests/test_flat.py) and for tensor-sharded lowering (launch/fed_dryrun).
 
@@ -241,12 +241,21 @@ from repro.sharding.rules import (
     padded_cohort,
 )
 from repro.utils.compat import shard_map
+from repro.utils.compile_cache import key_on_metadata
 from repro.utils.trees import (
     ravel_leaves,
     tree_axpy,
     tree_bytes,
     tree_zeros_like,
 )
+
+# Name scopes of the round program's layers (``flat.PLANE_VIEW_SCOPE`` is
+# the third): each client's loss, gradient and finalize, and the round
+# close with the round's metric norms.  They reach the compiled program's
+# ``op_name`` metadata only, where a device trace reads them; the compiled
+# code is the same with or without them.
+LOCAL_STEPS_SCOPE = "fedcm.local_steps"
+FOLD_SCOPE = "fedcm.fold"
 
 
 class FlatMaster(NamedTuple):
@@ -515,9 +524,9 @@ def flat_client_update(
     """One client's K local steps, finalized onto flat-engine outputs.
 
     jnp path: the local scan carries the LEAF form — model autodiff is
-    per-leaf anyway, and a flat↔tree conversion per step would add unfused
-    ops to the hottest loop (measured ~2-3× slower on CPU XLA) — so the
-    step body is bitwise the tree path's, and the client's outputs stay
+    per-leaf anyway, and a flat↔tree conversion per step would put a
+    concatenate and a split of the whole plane into every local step — so
+    the step body is bitwise the tree path's, and the client's outputs stay
     leaf trees with ``None`` for unused planes
     (``sparse_client_finalize``).  The engine then reduces them straight to
     flat ``(P,)`` MEANS — the full ``(C, P)`` cohort plane is never
@@ -536,26 +545,29 @@ def flat_client_update(
             return loss_fn(spec.unravel(flat), batch)
 
         def step(x, batch):
-            loss, g = jax.value_and_grad(flat_loss)(x, batch)
-            if cfg.weight_decay:
-                g = cfg.weight_decay * x + g
+            with jax.named_scope(LOCAL_STEPS_SCOPE):
+                loss, g = jax.value_and_grad(flat_loss)(x, batch)
+                if cfg.weight_decay:
+                    g = cfg.weight_decay * x + g
             x = flat_direction_step(algo, cfg, x, g, m_t, cst_flat_i, x_t, eta_l)
             return x, loss
 
         xK_flat, losses = jax.lax.scan(step, x_t, batches,
                                        unroll=cfg.local_steps if unroll else 1)
-        full_grad = None
-        if algo.needs_full_grad:
-            assert full_grad_batch is not None
-            full_grad = jax.grad(flat_loss)(x_t, full_grad_batch)
-        outs = sparse_client_finalize(algo, cfg, x_t, xK_flat, cst_flat_i,
-                                      m_t, eta_l, full_grad)
+        with jax.named_scope(LOCAL_STEPS_SCOPE):
+            full_grad = None
+            if algo.needs_full_grad:
+                assert full_grad_batch is not None
+                full_grad = jax.grad(flat_loss)(x_t, full_grad_batch)
+            outs = sparse_client_finalize(algo, cfg, x_t, xK_flat, cst_flat_i,
+                                          m_t, eta_l, full_grad)
         return outs, jnp.mean(losses)
 
     def step(x, batch):
-        loss, g = jax.value_and_grad(loss_fn)(x, batch)
-        if cfg.weight_decay:
-            g = tree_axpy(cfg.weight_decay, x, g)
+        with jax.named_scope(LOCAL_STEPS_SCOPE):
+            loss, g = jax.value_and_grad(loss_fn)(x, batch)
+            if cfg.weight_decay:
+                g = tree_axpy(cfg.weight_decay, x, g)
         v = algo.direction(cfg, m_tree, cst_tree_i, x, x0_tree, g)
         # keep the carry dtype stable (bf16 params + f32 momentum promote)
         x = jax.tree_util.tree_map(
@@ -565,12 +577,13 @@ def flat_client_update(
 
     xK, losses = jax.lax.scan(step, x0_tree, batches,
                               unroll=cfg.local_steps if unroll else 1)
-    full_grad = None
-    if algo.needs_full_grad:
-        assert full_grad_batch is not None
-        full_grad = jax.grad(loss_fn)(x0_tree, full_grad_batch)
-    outs = sparse_client_finalize(algo, cfg, x0_tree, xK, cst_tree_i,
-                                  m_tree, eta_l, full_grad)
+    with jax.named_scope(LOCAL_STEPS_SCOPE):
+        full_grad = None
+        if algo.needs_full_grad:
+            assert full_grad_batch is not None
+            full_grad = jax.grad(loss_fn)(x0_tree, full_grad_batch)
+        outs = sparse_client_finalize(algo, cfg, x0_tree, xK, cst_tree_i,
+                                      m_tree, eta_l, full_grad)
     return outs, jnp.mean(losses)
 
 
@@ -691,6 +704,10 @@ class FederatedEngine:
                     "alternative lowerings of the same axis — pass one"
                 )
             self._cohort_shards = cohort_axis_size(cohort_mesh)
+        # a device trace reads the layers by the name scopes in the
+        # compiled metadata, which the persistent cache must not answer
+        # from a build with other scopes (``key_on_metadata``)
+        key_on_metadata()
         self._round_step = jax.jit(self._round_step_impl)
         # traced once per (shapes, n_rounds) — the compile-count regression
         # test asserts a 100-round run is ONE trace, not 100
@@ -1415,42 +1432,43 @@ class FederatedEngine:
             )
 
         fsrv = fstate.server
-        if use_kernel and self._sharded:
-            new_params, new_server, mean_delta = self._sharded_round_close(
-                algo, fsrv, outs, wp, n_active, x_t, eta_l
-            )
-            new_server = new_server._replace(round=fsrv.round + 1)
-        elif use_kernel:
-            new_params, new_server, mean_delta = self._fused_round_close(
-                algo, fsrv, outs, w, n_active, x_t, eta_l
-            )
-            new_server = new_server._replace(round=fsrv.round + 1)
-        else:
-            if self._sharded:  # kernel-path spec with a server_fn escape
-                mean_delta, mean_sd, mean_extra = self._sharded_means(
-                    outs, wp, n_active
+        with jax.named_scope(FOLD_SCOPE):
+            if use_kernel and self._sharded:
+                new_params, new_server, mean_delta = self._sharded_round_close(
+                    algo, fsrv, outs, wp, n_active, x_t, eta_l
                 )
+                new_server = new_server._replace(round=fsrv.round + 1)
+            elif use_kernel:
+                new_params, new_server, mean_delta = self._fused_round_close(
+                    algo, fsrv, outs, w, n_active, x_t, eta_l
+                )
+                new_server = new_server._replace(round=fsrv.round + 1)
             else:
-                mean_delta = self._masked_pmean(outs.delta, w, n_active)
-                mean_sd = self._masked_pmean(outs.state_delta, w, n_active)
-                mean_extra = self._masked_pmean(outs.extra, w, n_active)
-            new_params, new_server = algo.server_update(
-                cfg, x_t, fsrv, mean_delta, mean_sd, mean_extra,
-                n_active, eta_l,
-            )
+                if self._sharded:  # kernel-path spec with a server_fn escape
+                    mean_delta, mean_sd, mean_extra = self._sharded_means(
+                        outs, wp, n_active
+                    )
+                else:
+                    mean_delta = self._masked_pmean(outs.delta, w, n_active)
+                    mean_sd = self._masked_pmean(outs.state_delta, w, n_active)
+                    mean_extra = self._masked_pmean(outs.extra, w, n_active)
+                new_params, new_server = algo.server_update(
+                    cfg, x_t, fsrv, mean_delta, mean_sd, mean_extra,
+                    n_active, eta_l,
+                )
 
-        # graceful degradation: a below-quorum (or empty) cohort carries
-        # params/momentum through unchanged — the guarded denominators
-        # already kept the fold finite, the select makes it a no-op (the
-        # round counter still advances; client-state writes are
-        # suppressed via the zeroed scatter weights)
-        ok = self._quorum_ok(n_active)
-        new_params = _where_tree(ok, new_params, x_t)
-        new_server = new_server._replace(
-            momentum=_where_tree(ok, new_server.momentum, fsrv.momentum),
-            second_moment=_where_tree(ok, new_server.second_moment,
-                                      fsrv.second_moment),
-        )
+            # graceful degradation: a below-quorum (or empty) cohort carries
+            # params/momentum through unchanged — the guarded denominators
+            # already kept the fold finite, the select makes it a no-op (the
+            # round counter still advances; client-state writes are
+            # suppressed via the zeroed scatter weights)
+            ok = self._quorum_ok(n_active)
+            new_params = _where_tree(ok, new_params, x_t)
+            new_server = new_server._replace(
+                momentum=_where_tree(ok, new_server.momentum, fsrv.momentum),
+                second_moment=_where_tree(ok, new_server.second_moment,
+                                          fsrv.second_moment),
+            )
         w_sc = w * ok.astype(jnp.float32)
 
         # scatter updated client states back (only active cohort members):
@@ -2134,63 +2152,64 @@ class FederatedEngine:
         fsrv = fstate.server
         use_kernel = cfg.use_fused_kernel and algo.server_fn is None
 
-        if use_kernel and self._sharded:
-            new_params, new_server, mean_delta = self._sharded_round_close(
-                algo, fsrv, entry, w, n_active, x_t, entry.eta_l,
-                discount=discount,
-            )
-        elif use_kernel:
-            new_params, new_server, mean_delta = self._fused_round_close(
-                algo, fsrv, entry, w, n_active, x_t, entry.eta_l,
-                discount=discount,
-            )
-        else:
-            if self._sharded:
-                # scattered reductions of the ring's sharded (C_pad, P)
-                # planes feeding the spec's server_fn escape hatch
-                mean_delta, mean_sd, mean_extra = self._sharded_means(
-                    entry, w, n_active
+        with jax.named_scope(FOLD_SCOPE):
+            if use_kernel and self._sharded:
+                new_params, new_server, mean_delta = self._sharded_round_close(
+                    algo, fsrv, entry, w, n_active, x_t, entry.eta_l,
+                    discount=discount,
                 )
-            elif cfg.use_fused_kernel:
-                # kernel-path algorithm whose round-close is a ``server_fn``
-                # escape hatch: reduce the raw (C, P) planes exactly as the
-                # sync kernel path does
-                mean_delta = self._masked_pmean(entry.delta, w, n_active)
-                mean_sd = self._masked_pmean(entry.state_delta, w, n_active)
-                mean_extra = self._masked_pmean(entry.extra, w, n_active)
+            elif use_kernel:
+                new_params, new_server, mean_delta = self._fused_round_close(
+                    algo, fsrv, entry, w, n_active, x_t, entry.eta_l,
+                    discount=discount,
+                )
             else:
-                # jnp path: delta/extra were pre-reduced at launch (the
-                # weights are launch-time constants — same value, same
-                # reduction, C× less ring state); only the per-client
-                # state plane still needs its mean, reduced per leaf VIEW
-                # so the contraction shapes match the sync round's exactly
-                # (one plane-wide tensordot schedules its accumulation
-                # differently and would break D=1 bitwise equality)
-                mean_delta = entry.delta
-                mean_extra = entry.extra
-                mean_sd = None
-                if entry.state_delta is not None:
-                    mean_sd = self._masked_pmean(
-                        spec.unravel(entry.state_delta, dtype=jnp.float32),
-                        w, n_active,
+                if self._sharded:
+                    # scattered reductions of the ring's sharded (C_pad, P)
+                    # planes feeding the spec's server_fn escape hatch
+                    mean_delta, mean_sd, mean_extra = self._sharded_means(
+                        entry, w, n_active
                     )
-            # the γ=1 sync fold stays bitwise: spec.server_update skips the
-            # statically-1.0 discount multiply
-            new_params, new_server = algo.server_update(
-                cfg, x_t, fsrv, mean_delta, mean_sd, mean_extra,
-                n_active, entry.eta_l, discount=discount,
-            )
-            new_server = new_server._replace(round=fsrv.round)
+                elif cfg.use_fused_kernel:
+                    # kernel-path algorithm whose round-close is a ``server_fn``
+                    # escape hatch: reduce the raw (C, P) planes exactly as the
+                    # sync kernel path does
+                    mean_delta = self._masked_pmean(entry.delta, w, n_active)
+                    mean_sd = self._masked_pmean(entry.state_delta, w, n_active)
+                    mean_extra = self._masked_pmean(entry.extra, w, n_active)
+                else:
+                    # jnp path: delta/extra were pre-reduced at launch (the
+                    # weights are launch-time constants — same value, same
+                    # reduction, C× less ring state); only the per-client
+                    # state plane still needs its mean, reduced per leaf VIEW
+                    # so the contraction shapes match the sync round's exactly
+                    # (one plane-wide tensordot schedules its accumulation
+                    # differently and would break D=1 bitwise equality)
+                    mean_delta = entry.delta
+                    mean_extra = entry.extra
+                    mean_sd = None
+                    if entry.state_delta is not None:
+                        mean_sd = self._masked_pmean(
+                            spec.unravel(entry.state_delta, dtype=jnp.float32),
+                            w, n_active,
+                        )
+                # the γ=1 sync fold stays bitwise: spec.server_update skips the
+                # statically-1.0 discount multiply
+                new_params, new_server = algo.server_update(
+                    cfg, x_t, fsrv, mean_delta, mean_sd, mean_extra,
+                    n_active, entry.eta_l, discount=discount,
+                )
+                new_server = new_server._replace(round=fsrv.round)
 
-        # below-quorum / empty fold → no-op (see _flat_round_step); the
-        # zeroed weights also suppress the client-state writes below
-        ok = self._quorum_ok(n_active)
-        new_params = _where_tree(ok, new_params, x_t)
-        new_server = new_server._replace(
-            momentum=_where_tree(ok, new_server.momentum, fsrv.momentum),
-            second_moment=_where_tree(ok, new_server.second_moment,
-                                      fsrv.second_moment),
-        )
+            # below-quorum / empty fold → no-op (see _flat_round_step); the
+            # zeroed weights also suppress the client-state writes below
+            ok = self._quorum_ok(n_active)
+            new_params = _where_tree(ok, new_params, x_t)
+            new_server = new_server._replace(
+                momentum=_where_tree(ok, new_server.momentum, fsrv.momentum),
+                second_moment=_where_tree(ok, new_server.second_moment,
+                                          fsrv.second_moment),
+            )
         w = w * ok.astype(jnp.float32)
         skipped = 1.0 - ok.astype(jnp.float32)
 
@@ -2672,8 +2691,10 @@ def _tree_norm(t):
 
 def _flat_norm(x):
     """‖x‖₂ of one flat plane — same formulation as ``_tree_norm`` so flat
-    and tree metrics agree bitwise for single-buffer input."""
-    return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+    and tree metrics agree bitwise for single-buffer input.  The round's
+    metric norms count to the round close's scope."""
+    with jax.named_scope(FOLD_SCOPE):
+        return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
 
 
 # ----------------------------------------------------------------------

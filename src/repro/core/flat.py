@@ -52,6 +52,15 @@ import numpy as np
 
 from repro.utils.trees import ravel_leaves, split_flat
 
+# Name scope of every conversion between the plane and its leaf views
+# (``FlatSpec.ravel`` / ``unravel``).  It reaches the compiled program's
+# ``op_name`` metadata, through autodiff as
+# ``transpose(jvp(fedcm.plane_view))`` on the gradient's pads and adds back
+# into the plane, so a device trace can attribute the plane's copies.  A
+# fusion counts by its root: where XLA fuses the gradient's accumulation
+# into the weight-decay add, that fusion carries ``fedcm.local_steps``.
+PLANE_VIEW_SCOPE = "fedcm.plane_view"
+
 
 class LeafSpec(NamedTuple):
     """Static layout of one leaf inside the flat plane."""
@@ -107,7 +116,8 @@ class FlatSpec:
         downstream operates on the buffer.
         """
         leaves = self.treedef.flatten_up_to(tree)
-        return ravel_leaves(leaves, dtype=dtype, batch_dims=batch_dims)
+        with jax.named_scope(PLANE_VIEW_SCOPE):
+            return ravel_leaves(leaves, dtype=dtype, batch_dims=batch_dims)
 
     def unravel(self, flat: jax.Array, dtype=None):
         """Buffer ``(*lead, P)`` → tree of ``(*lead, *shape)`` leaves.
@@ -117,7 +127,8 @@ class FlatSpec:
         their consumers — no per-step copy.
         """
         dtypes = [dtype or l.dtype for l in self.leaves]
-        leaves = split_flat(flat, [l.shape for l in self.leaves], dtypes)
+        with jax.named_scope(PLANE_VIEW_SCOPE):
+            leaves = split_flat(flat, [l.shape for l in self.leaves], dtypes)
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def view_leaf(self, flat: jax.Array, key: Union[int, str], dtype=None):
