@@ -105,12 +105,20 @@ def plane_key(key, name: str):
 # ---------------------------------------------------------------- int8
 
 
-def quantize_int8(plane, key) -> QPlane:
-    """Per-row absmax-scaled stochastic rounding to int8 (unbiased)."""
+def quantize_int8(plane, key, size: Optional[int] = None) -> QPlane:
+    """Per-row absmax-scaled stochastic rounding to int8 (unbiased).
+
+    Only the first ``size`` elements of each row (default: all) draw a
+    rounding offset, so a plane's zero tail (``FlatSpec.plane_size``)
+    neither shifts the draws of the parameters nor rounds away from 0."""
     amax = jnp.max(jnp.abs(plane), axis=-1, keepdims=True)
     # zero rows (dropped/quarantined clients) get scale 1 → q stays 0
     scale = jnp.where(amax > 0, amax / INT8_LEVELS, 1.0).astype(jnp.float32)
-    u = jax.random.uniform(key, plane.shape, jnp.float32)
+    n = plane.shape[-1]
+    size = n if size is None else size
+    u = jax.random.uniform(key, (*plane.shape[:-1], size), jnp.float32)
+    if size < n:
+        u = jnp.pad(u, [(0, 0)] * (plane.ndim - 1) + [(0, n - size)])
     q = jnp.clip(jnp.floor(plane / scale + u), -INT8_LEVELS, INT8_LEVELS)
     return QPlane(q=q.astype(jnp.int8), scale=scale)
 
@@ -150,19 +158,22 @@ def densify_topk(rep: TopKPlane, n: int):
     return out.at[jnp.arange(C)[:, None], rep.idx].set(rep.values)
 
 
-def error_feedback_topk(comp, plane, residual_rows, active, n: int):
+def error_feedback_topk(comp, plane, residual_rows, active, size: int):
     """One error-feedback round for the cohort's delta plane.
 
     ``plane`` (C, n) is the raw uplink, ``residual_rows`` (C, n) the
     cohort's gathered residuals, ``active`` (C,) the post-fault weight
     row (a client that did not transmit keeps its residual untouched).
+    ``size`` is the parameter count k is a fraction of; a plane may be
+    longer (``FlatSpec.plane_size``), and its zero tail is only picked
+    where a row holds fewer than k nonzeros, which keeps it zero.
     Returns ``(rep, recon, new_residual_rows)`` where ``recon`` is the
     dense plane the server folds (exactly what arrived on the wire) and
     ``new_residual_rows = accumulated − sent`` for active rows.
     """
     acc = plane + residual_rows
-    rep = sparsify_topk(acc, topk_k(comp, n))
-    recon = densify_topk(rep, n)
+    rep = sparsify_topk(acc, topk_k(comp, size))
+    recon = densify_topk(rep, plane.shape[-1])
     keep = (active > 0)[:, None]
     new_rows = jnp.where(keep, acc - recon, residual_rows)
     # inactive rows must fold as zeros, not as their stale accumulator
@@ -173,10 +184,11 @@ def error_feedback_topk(comp, plane, residual_rows, active, n: int):
 # ------------------------------------------------------------ dispatch
 
 
-def compress_plane(comp, plane, key):
-    """Dense f32 plane → wire representation (int8/bf16 kinds)."""
+def compress_plane(comp, plane, key, size: Optional[int] = None):
+    """Dense f32 plane → wire representation (int8/bf16 kinds); ``size``
+    as in ``quantize_int8``."""
     if comp.kind == "int8":
-        return quantize_int8(plane, key)
+        return quantize_int8(plane, key, size)
     if comp.kind == "bf16":
         return quantize_bf16(plane)
     raise ValueError(f"compress_plane does not handle kind {comp.kind!r}")

@@ -84,7 +84,9 @@ concatenate and split the whole plane), so its body is bitwise the tree
 path's.  Under ``use_fused_kernel`` the scan flips to the flat ``(P,)``
 carry — the kernels consume flat buffers directly, per-client control
 variates ride an ``(N, P)`` plane (ONE gather/scatter), and a tree-form
-kernel's per-step concatenate/split is gone.  The tree path
+kernel's per-step concatenate/split is gone.  There the plane carries a
+zero tail up to the kernels' block length (``_flat_spec``), so no launch
+pads or slices a plane.  The tree path
 (``use_flat_plane=False``) is retained verbatim as the numerical oracle
 (tests/test_flat.py) and for tensor-sharded lowering (launch/fed_dryrun).
 
@@ -232,6 +234,7 @@ from repro.data.population import (
     availability_log_weights,
     make_population_store,
 )
+from repro.kernels import plane_alignment
 from repro.kernels.fed_direction.ops import flat_direction_step
 from repro.kernels.server_update.ops import fused_fold, scatter_fold
 from repro.sharding.rules import (
@@ -749,14 +752,14 @@ class FederatedEngine:
         (re-``init`` = a fresh population)."""
         if self.population_store != "resident" and self.algo.needs_client_state:
             self.population = make_population_store(
-                self.cfg, FlatSpec.from_tree(params).size
+                self.cfg, self._flat_spec(params).plane_size
             )
         # top-k error-feedback residuals are a per-client state stream of
         # their own: resident (N, P) zeros, or a second host store whose
         # unwritten rows read as zeros (same init semantics)
         residuals = None
         if self._ef_residuals:
-            size = FlatSpec.from_tree(params).size
+            size = self._flat_spec(params).plane_size
             if self.population_store == "resident":
                 residuals = jnp.zeros(
                     (self.cfg.num_clients, size), jnp.float32
@@ -779,7 +782,7 @@ class FederatedEngine:
         # contract from round 0
         if self.cfg.use_flat_plane:
             try:
-                spec = FlatSpec.from_tree(params)
+                spec = self._flat_spec(params)
             except TypeError:  # non-float leaves: flat path will refuse anyway
                 return state
             if self._needs_master(spec):
@@ -808,6 +811,17 @@ class FederatedEngine:
     def _ef_residuals(self) -> bool:
         """True when top-k compression carries an error-feedback stream."""
         return self.compression is not None and self.compression.kind == "topk"
+
+    def _flat_spec(self, params) -> FlatSpec:
+        """The flat plane of ``params`` as this engine lays it out.  On the
+        kernel path its length is a multiple of the launches' block
+        (``plane_alignment`` of the size and the cohort shards), so the
+        kernels neither pad their operands nor slice their outputs; the
+        jnp path's plane is the leaves alone."""
+        spec = FlatSpec.from_tree(params)
+        if not self.cfg.use_fused_kernel:
+            return spec
+        return spec.aligned(plane_alignment(spec.size, self._cohort_shards))
 
     # -------------------------------------------------- payload accounting
     def payload_bytes(self, params) -> Dict[str, int]:
@@ -1096,12 +1110,18 @@ class FederatedEngine:
         the replicated planes with the discount-weighted mean.  ONE
         implementation — the sync/async and sharded/unsharded closes must
         never drift in how γ reaches the post."""
-        new_server = fsrv._replace(momentum=new_m)
-        if algo.server_post_fn is not None:
-            dmean = mean_delta if discount == 1.0 else discount * mean_delta
-            new_x, new_server = algo.server_post_fn(
-                self.cfg, new_x, new_server, dmean, n_active, eta_l
-            )
+        if algo.server_post_fn is None:
+            return new_x, fsrv._replace(momentum=new_m), mean_delta
+        # the post starts from materialized planes on every route: fused
+        # into what produced them (a kernel's output on one device, an
+        # all_gather on the cohort mesh), XLA:CPU contracts its mul-adds
+        # differently per route, and sharded runs lose bitwise equality
+        new_x, new_m, mean_delta = jax.lax.optimization_barrier(
+            (new_x, new_m, mean_delta))
+        dmean = mean_delta if discount == 1.0 else discount * mean_delta
+        new_x, new_server = algo.server_post_fn(
+            self.cfg, new_x, fsrv._replace(momentum=new_m), dmean, n_active, eta_l
+        )
         return new_x, new_server, mean_delta
 
     def _sharded_means(self, outs, wp, n_active):
@@ -1348,7 +1368,8 @@ class FederatedEngine:
                 # everything else folds the dense decoded payload
                 planes[name] = rep if (ring and kernel_fold) else recon
                 continue
-            rep = as_qplane(compress_plane(comp, pv, plane_key(key, name)))
+            rep = as_qplane(compress_plane(comp, pv, plane_key(key, name),
+                                           spec.size))
             if not kernel_fold:
                 # server_fn escape hatch reduces via _masked_pmean: decode
                 planes[name] = decompress_plane(rep)
@@ -1370,7 +1391,7 @@ class FederatedEngine:
             return entry
         if isinstance(entry.delta, TopKPlane):
             entry = entry._replace(
-                delta=decompress_plane(entry.delta, spec.size)
+                delta=decompress_plane(entry.delta, spec.plane_size)
             )
         return entry
 
@@ -1565,7 +1586,7 @@ class FederatedEngine:
     # -------------------------------------------------- round
     def _round_step_impl(self, state: FedState, batches, ids, mask, full_batches):
         if self.cfg.use_flat_plane:
-            spec = FlatSpec.from_tree(state.params)
+            spec = self._flat_spec(state.params)
             fstate = self._ravel_state(state, spec)
             fstate, metrics = self._flat_round_step(
                 fstate, batches, ids, mask, full_batches, spec
@@ -1758,7 +1779,7 @@ class FederatedEngine:
         if self.cfg.use_flat_plane:
             # ravel ONCE for the whole N-round program; the scan carries
             # (P,)/(N,P) planes and unravels once at the end
-            spec = FlatSpec.from_tree(state.params)
+            spec = self._flat_spec(state.params)
             fstate = self._ravel_state(state, spec)
 
             def flat_body(st, _):
@@ -1897,7 +1918,7 @@ class FederatedEngine:
         cfg, algo = self.cfg, self.algo
         D, S = pipeline_depth, staleness
 
-        spec = FlatSpec.from_tree(state.params)
+        spec = self._flat_spec(state.params)
         fstate = self._ravel_state(state, spec)
         # momentum delay line: slot t mod S holds the broadcast buffer as it
         # was ENTERING round t−S (read-before-write); seeded with the
@@ -2028,7 +2049,7 @@ class FederatedEngine:
         clone the entire scan body around the last iteration — one
         fixed-size epilogue program is cheaper than that, and its operands
         never leave the device."""
-        spec = FlatSpec.from_tree(state.params)
+        spec = self._flat_spec(state.params)
         fstate = self._ravel_state(state, spec)
         # the same staleness weight the in-scan folds used (depth, not
         # len(pending): a shorter-than-depth run still launched at the
@@ -2403,7 +2424,7 @@ class FederatedEngine:
         cfg = self.cfg
         if n_rounds < 1:
             raise ValueError(f"n_rounds must be >= 1, got {n_rounds}")
-        spec = FlatSpec.from_tree(state.params)
+        spec = self._flat_spec(state.params)
         jits = self._store_jits(spec)
         fstate = self._ravel_state(state, spec)
         device_data = hasattr(data, "client_x")
@@ -2593,7 +2614,7 @@ class FederatedEngine:
             raise ValueError(f"pipeline_depth must be >= 1, got {D}")
         if S < 0:
             raise ValueError(f"staleness must be >= 0, got {S}")
-        spec = FlatSpec.from_tree(state.params)
+        spec = self._flat_spec(state.params)
         jits = self._store_jits(spec)
         fstate = self._ravel_state(state, spec)
         device_data = hasattr(data, "client_x")

@@ -15,11 +15,18 @@ step.  ``FlatSpec`` fixes the representation instead:
 * ``view_leaf(flat, key)`` → a single leaf without materializing the tree.
 
 The layout is the static offset table ``spec.leaves`` (path, shape, dtype,
-offset, size) in treedef order, no alignment padding — kernels pad the
-*tail* of the whole plane to their block size instead (see
-``src/repro/kernels/README.md``).  Buffers with leading batch axes reuse the
-same table: a cohort delta plane is ``(C, P)``, stacked per-client control
-variates are ``(N, P)``; ``unravel`` restores ``(..., *shape)`` leaves.
+offset, size) in treedef order, with no padding between leaves.  The
+plane may end in a zero tail: ``spec.size`` is the leaves' total P,
+``spec.plane_size`` the plane's length P' ≥ P, a multiple of the
+``align`` the spec was built with (``FlatSpec.aligned``).  The engine
+aligns the kernel path's plane to the kernels' block length
+(``repro.kernels.plane_alignment``), so no launch pads its operands or
+slices its outputs; ``ravel`` writes the tail as zeros, ``unravel`` and
+``view_leaf`` never read it, and every affine direction and fold row
+keeps it zero (see ``src/repro/kernels/README.md``).  Buffers with
+leading batch axes reuse the same table: a cohort delta plane is
+``(C, P')``, stacked per-client control variates are ``(N, P')``;
+``unravel`` restores ``(..., *shape)`` leaves.
 
 ``FederatedEngine`` ravels params/momentum/client-state once per
 ``run_rounds`` call and carries the planes through the local-step scan, the
@@ -79,12 +86,20 @@ class FlatSpec:
     building one is pure python and happens at trace time.
     """
 
-    __slots__ = ("treedef", "leaves", "size")
+    __slots__ = ("treedef", "leaves", "size", "plane_size")
 
-    def __init__(self, treedef, leaves: Tuple[LeafSpec, ...]):
+    def __init__(self, treedef, leaves: Tuple[LeafSpec, ...], align: int = 1):
         self.treedef = treedef
         self.leaves = leaves
+        # the leaves' total P: what payload accounting charges
         self.size = (leaves[-1].offset + leaves[-1].size) if leaves else 0
+        # the plane's length P': P rounded up to ``align``, the tail zero
+        self.plane_size = -(-self.size // align) * align
+
+    def aligned(self, align: int) -> "FlatSpec":
+        """The same layout on a plane whose length is a multiple of
+        ``align`` (a zero tail after the last leaf)."""
+        return FlatSpec(self.treedef, self.leaves, align)
 
     # ------------------------------------------------------------- build
     @classmethod
@@ -108,19 +123,25 @@ class FlatSpec:
 
     # ------------------------------------------------------------- ravel
     def ravel(self, tree, dtype=jnp.float32, batch_dims: int = 0) -> jax.Array:
-        """Tree → one contiguous ``(*lead, P)`` buffer in ``dtype``.
+        """Tree → one contiguous ``(*lead, P')`` buffer in ``dtype``.
 
         ``batch_dims`` leading axes of every leaf (e.g. the stacked-client
         axis of ``(N, *shape)`` state) are preserved in front of the plane
         axis.  This is the ONE concatenate of the flat engine — everything
-        downstream operates on the buffer.
+        downstream operates on the buffer.  The zero tail is one more
+        operand of that concatenate.
         """
         leaves = self.treedef.flatten_up_to(tree)
+        tail = self.plane_size - self.size
+        if tail:
+            lead = leaves[0].shape[:batch_dims]
+            leaves = [*leaves, jnp.zeros((*lead, tail), dtype)]
         with jax.named_scope(PLANE_VIEW_SCOPE):
             return ravel_leaves(leaves, dtype=dtype, batch_dims=batch_dims)
 
     def unravel(self, flat: jax.Array, dtype=None):
-        """Buffer ``(*lead, P)`` → tree of ``(*lead, *shape)`` leaves.
+        """Buffer ``(*lead, P')`` → tree of ``(*lead, *shape)`` leaves (the
+        tail is never read).
 
         Leaf dtypes are restored from the table (pass ``dtype`` to override,
         e.g. a uniform momentum dtype).  Under jit the slices fuse into
@@ -155,13 +176,15 @@ class FlatSpec:
             isinstance(other, FlatSpec)
             and self.treedef == other.treedef
             and self.leaves == other.leaves
+            and self.plane_size == other.plane_size
         )
 
     def __hash__(self) -> int:
-        return hash((self.treedef, self.leaves))
+        return hash((self.treedef, self.leaves, self.plane_size))
 
     def __repr__(self) -> str:
-        return f"FlatSpec(n_leaves={len(self.leaves)}, size={self.size})"
+        return (f"FlatSpec(n_leaves={len(self.leaves)}, size={self.size}, "
+                f"plane_size={self.plane_size})")
 
 
 # ----------------------------------------------------------------------

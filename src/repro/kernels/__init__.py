@@ -14,6 +14,8 @@ tests).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
 
@@ -37,3 +39,22 @@ def interpret_mode() -> bool:
         f"Pallas kernels compile for TPU (Mosaic) or interpret on CPU; "
         f"the {platform!r} backend has neither"
     )
+
+
+def plane_alignment(size: int, n_shards: int) -> int:
+    """The length the flat plane of the kernel path is a multiple of
+    (``FlatSpec.aligned``), so that no launch pads or slices it.
+
+    A plane of ``size`` elements or more than one ``fed_direction`` block
+    aligns to that block and to ``server_update``'s block on each of the
+    ``n_shards`` plane-column chunks of the scattered fold: 65,536 for 1,
+    2 or 4 shards.  A shorter plane aligns to the fold's tile on each
+    chunk instead, and ``fed_direction`` launches it as one block.
+    """
+    from repro.kernels.fed_direction.kernel import DEFAULT_BLOCK as DIRECTION_BLOCK
+    from repro.kernels.server_update.kernel import DEFAULT_BLOCK as FOLD_BLOCK
+    from repro.kernels.server_update.ops import TILE
+
+    if size < DIRECTION_BLOCK:
+        return TILE * n_shards
+    return math.lcm(DIRECTION_BLOCK, FOLD_BLOCK * n_shards)

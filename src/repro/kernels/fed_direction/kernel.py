@@ -18,7 +18,10 @@ roofline floor for the op (AI ≈ 0.5 flop/byte; it is purely memory-bound).
 
 Tiling mirrors kernels/fedcm_update: the flat plane is padded to a multiple
 of ``block_elems`` and viewed as (padded//LANE, LANE) so every BlockSpec
-tile is a VMEM-resident (rows, 128) slab.  The coefficient vector
+tile is a VMEM-resident (rows, 128) slab.  The engine's plane already is
+such a multiple (``FlatSpec.plane_size``), so there the pad has width 0,
+the output slice is the whole plane, and neither copies; they stay for
+callers with any other length.  The coefficient vector
 (η_l, c_g, c_x, c_aux...) rides in SMEM as a (1, 3+n_aux) row — η_l decays
 per round and several coefficients are traced, so baking them as python
 constants would force a recompile per round.

@@ -30,7 +30,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro import kernels
-from repro.kernels.fed_direction.kernel import fed_direction_flat
+from repro.kernels.fed_direction.kernel import DEFAULT_BLOCK, fed_direction_flat
+from repro.kernels.server_update.ops import TILE
 
 
 def _coefs(eta_l, c_g, c_x, *c_aux):
@@ -71,4 +72,12 @@ def flat_direction_step(algo, cfg, x, g, m, cst, x0, eta_l):
         auxes.append(x0)
         aux_coefs.append(-c_x)
     coefs = _coefs(eta_l, c_g, c_x, *aux_coefs)
-    return fed_direction_flat(x, g, tuple(auxes), coefs, interpret=kernels.interpret_mode())
+    return fed_direction_flat(x, g, tuple(auxes), coefs, block_elems=_block(x.shape[-1]),
+                              interpret=kernels.interpret_mode())
+
+
+def _block(n: int) -> int:
+    """The default block, or for a plane shorter than it, one block of the
+    plane rounded up to whole tiles: the engine aligns short planes to
+    ``TILE`` (``repro.kernels.plane_alignment``), so they launch unpadded."""
+    return DEFAULT_BLOCK if n >= DEFAULT_BLOCK else -(-n // TILE) * TILE

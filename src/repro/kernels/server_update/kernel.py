@@ -32,8 +32,9 @@ Coefficient mapping (see core/engine.py):
 * mimelite     : params pass (1, 0, η_g) over Δ, momentum pass
   (1−α, α, 0) over the full-batch-grad plane.
 
-Tiling: planes are padded to a multiple of ``block_elems`` and viewed as
-(padded//LANE, LANE); the delta plane blocks as (C, rows, LANE) — the whole
+Tiling: planes are padded to a multiple of ``block_elems`` (a width-0 pad
+for the engine's aligned plane, ``FlatSpec.plane_size``, whose column
+chunks are whole blocks too) and viewed as (padded//LANE, LANE); the delta plane blocks as (C, rows, LANE) — the whole
 cohort column is resident per grid step (C is a cohort, 8–64, so a block is
 C·256 KiB of VMEM at the default; shrink ``block_elems`` for huge cohorts).
 ``wn`` is lane-padded to (C, LANE) (column 0 live) instead of an unaligned

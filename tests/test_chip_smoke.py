@@ -93,8 +93,8 @@ def test_compare_catches_a_kernel_that_drops_momentum(smoke, tiny_setting,
     carries zero momentum, so only later rounds can show it."""
     real = fed_direction_ops.fed_direction_flat
 
-    def drops_momentum(x, g, auxes, coefs, *, interpret):
-        return real(x, g, auxes, coefs.at[3:].set(0.0), interpret=interpret)
+    def drops_momentum(x, g, auxes, coefs, **launch):
+        return real(x, g, auxes, coefs.at[3:].set(0.0), **launch)
 
     monkeypatch.setattr(fed_direction_ops, "fed_direction_flat", drops_momentum)
     failures = []

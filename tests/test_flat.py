@@ -8,6 +8,9 @@
 * Donation: run_rounds donates its input state; the returned trajectory
   must be stable when the donated buffers get recycled by later calls.
 * Mixed bf16/f32 trees survive the flat round trip.
+* The kernel path's aligned plane: a zero tail that ``ravel`` writes,
+  ``unravel`` ignores and every algorithm keeps exactly zero, while
+  payload accounting charges the leaves alone.
 """
 from dataclasses import replace
 
@@ -17,7 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import FedConfig
+from repro.configs.base import CompressionConfig, FedConfig
 from repro.core import FederatedEngine, FlatSpec, list_algorithms
 from repro.data import FederatedData, make_synthetic_classification
 from repro.models.small import classification_loss, mlp_classifier
@@ -108,6 +111,33 @@ def test_flatspec_empty_tree():
     spec = FlatSpec.from_tree({})
     assert spec.size == 0
     assert spec.ravel({}).shape == (0,)
+
+
+def test_flatspec_aligned_plane_roundtrips_with_zero_tail():
+    tree = _mixed_tree()
+    plain = FlatSpec.from_tree(tree)
+    spec = plain.aligned(64)
+    assert (spec.size, spec.plane_size) == (103, 128)
+    assert plain.plane_size == plain.size and plain.aligned(1) == plain
+    assert spec != plain and hash(spec) == hash(plain.aligned(64))
+    flat = spec.ravel(tree)
+    assert flat.shape == (128,)
+    np.testing.assert_array_equal(np.asarray(flat[:103]), np.asarray(plain.ravel(tree)))
+    np.testing.assert_array_equal(np.asarray(flat[103:]), 0.0)
+    # the tail is never read: whatever it holds, the leaves come back
+    back = spec.unravel(flat.at[103:].set(7.0))
+    for o, r in zip(jax.tree_util.tree_leaves(plain.unravel(plain.ravel(tree))),
+                    jax.tree_util.tree_leaves(back)):
+        assert o.dtype == r.dtype
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
+    np.testing.assert_array_equal(np.asarray(spec.view_leaf(flat, 0)),
+                                  np.asarray(tree["a"]))
+    # stacked leading axes carry the tail on every row
+    per_client = {"w": jax.ShapeDtypeStruct((3, 2), jnp.float32)}
+    stacked = {"w": jnp.asarray(RNG.normal(size=(4, 3, 2)), jnp.float32)}
+    rows = FlatSpec.from_tree(per_client).aligned(8).ravel(stacked, batch_dims=1)
+    assert rows.shape == (4, 8)
+    np.testing.assert_array_equal(np.asarray(rows[:, 6:]), 0.0)
 
 
 # ----------------------------------------------------------------------
@@ -236,6 +266,72 @@ def test_flat_engine_bf16_mixed_param_tree():
         jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), newt.params),
         atol=2e-2, rtol=2e-2,
     )
+
+
+# ----------------------------------------------------------------------
+# the kernel path's aligned plane
+# ----------------------------------------------------------------------
+
+
+def _tails(fstate, P):
+    """The zero tails of every plane a flat state carries."""
+    planes = {"x": fstate.params, "m": fstate.server.momentum,
+              "v": fstate.server.second_moment, "c_i": fstate.client_states,
+              "residual": fstate.residuals}
+    return {k: np.asarray(v)[..., P:] for k, v in planes.items() if v is not None}
+
+
+_TAIL_CASES = [pytest.param(a, {}, "sync", id=a) for a in list_algorithms()] + [
+    pytest.param("scaffold", dict(cohort_shard=1), "sync", id="scaffold-sharded"),
+    pytest.param("scaffold", dict(compression=CompressionConfig(kind="int8")),
+                 "sync", id="scaffold-int8"),
+    pytest.param("fedcm", dict(compression=CompressionConfig(kind="topk", topk_frac=0.1)),
+                 "sync", id="fedcm-topk"),
+    pytest.param("scaffold", {}, "async", id="scaffold-async"),
+]
+
+
+@pytest.mark.parametrize("algo,kw,mode", _TAIL_CASES)
+def test_kernel_path_plane_tail_stays_zero(algo, kw, mode):
+    """Every plane the kernel path carries keeps its zero tail exactly
+    zero through several rounds: x, m, the client state, the top-k
+    residuals and, on the async ring, the in-flight uplinks."""
+    _, eng, data, model = _setup(algo, use_fused_kernel=True, **kw)
+    st = _fresh(eng, model)
+    spec = eng._flat_spec(st.params)
+    assert spec.plane_size == 4096 > spec.size  # 212 leaves, one tile
+    eng._unravel_state = lambda fstate, spec: fstate  # hand back the planes
+    if mode == "sync":
+        fstate, _ = eng.run_rounds(st, data, 4)
+        pending = ()
+    else:
+        fstate, pending, _ = eng._run_rounds_async(
+            st, data.client_x, data.client_y, None, None, None, n_rounds=4,
+            pipeline_depth=2, staleness=1, eval_every=0, predict_fn=None)
+    assert fstate.params.shape == (spec.plane_size,)
+    for name, tail in _tails(fstate, spec.size).items():
+        np.testing.assert_array_equal(tail, 0.0, err_msg=name)
+    for entry in pending:
+        for name in ("delta", "state_delta", "extra"):
+            v = getattr(entry, name)
+            if v is not None:
+                np.testing.assert_array_equal(np.asarray(v)[..., spec.size:], 0.0,
+                                              err_msg=name)
+
+
+@pytest.mark.parametrize("kind", [None, "int8", "topk"])
+def test_aligned_plane_charges_the_leaves(kind):
+    """Payload accounting charges the leaves' P, not the kernel path's P'."""
+    comp = None if kind is None else CompressionConfig(kind=kind, topk_frac=0.1)
+    bytes_up = {}
+    for kernel in (False, True):
+        _, eng, data, model = _setup("scaffold", use_fused_kernel=kernel,
+                                     compression=comp)
+        st = _fresh(eng, model)
+        payload = eng.payload_bytes(st.params)
+        _, ms = eng.run_rounds(st, data, 1)
+        bytes_up[kernel] = (float(ms.bytes_up[0]), float(ms.bytes_down[0]), payload)
+    assert bytes_up[True] == bytes_up[False]
 
 
 # ----------------------------------------------------------------------

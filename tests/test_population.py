@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from repro.checkpoint.ckpt import load_checkpoint, load_flat, save_checkpoint
 from repro.configs.base import FedConfig
 from repro.core import FederatedEngine, cohort_capacity, sample_cohort, sample_cohort_ex
-from repro.core.flat import FlatSpec
 from repro.data import FederatedData, make_synthetic_classification
 from repro.data.population import (
     HostPopulationStore,
@@ -64,8 +63,10 @@ def _assert_bitwise(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
-def _store_rows_vs_resident(eng_host, resident_state, spec):
-    """Dense (N, P) view of the host store vs the resident stacked plane."""
+def _store_rows_vs_resident(eng_host, resident_state):
+    """Dense (N, P') view of the host store vs the resident stacked plane,
+    both in the engine's plane layout (its zero tail included)."""
+    spec = eng_host._flat_spec(resident_state.params)
     rows_ref = np.asarray(spec.ravel(resident_state.client_states, batch_dims=1))
     tree = eng_host.population.to_pytree()
     dense = np.zeros_like(rows_ref)
@@ -97,7 +98,7 @@ def test_store_sync_bitwise_vs_resident(algo, kernel):
     _assert_bitwise((sr.params, sr.server.momentum),
                     (sh.params, sh.server.momentum))
     np.testing.assert_array_equal(np.stack(losses), np.asarray(mh.loss))
-    _store_rows_vs_resident(eng_h, sr, FlatSpec.from_tree(sr.params))
+    _store_rows_vs_resident(eng_h, sr)
 
 
 @pytest.mark.parametrize("algo", ["scaffold", "feddyn"])
@@ -116,7 +117,7 @@ def test_store_async_kernel_bitwise_vs_resident(algo):
                     (sh.params, sh.server.momentum))
     np.testing.assert_array_equal(np.asarray(mr.loss), np.asarray(mh.loss))
     np.testing.assert_array_equal(np.asarray(mr.folded), np.asarray(mh.folded))
-    _store_rows_vs_resident(eng_h, sr, FlatSpec.from_tree(sr.params))
+    _store_rows_vs_resident(eng_h, sr)
 
 
 @pytest.mark.parametrize("algo", ["scaffold", "feddyn"])

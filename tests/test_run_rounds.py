@@ -39,9 +39,9 @@ from repro.utils.trees import tree_cast
 N_ROUNDS = 5
 
 
-def _setup(algo, **kw):
+def _setup(algo, widths=(8, 16, 4), **kw):
     x, y, *_ = make_synthetic_classification(n_classes=4, dim=8, n_train=800, n_test=8)
-    model = mlp_classifier((8, 16, 4))
+    model = mlp_classifier(widths)
     base = dict(algo=algo, num_clients=10, cohort_size=3, local_steps=2,
                 participation="fixed")
     base.update(kw)
@@ -115,12 +115,21 @@ def test_run_rounds_rejects_nonpositive():
         eng.run_rounds(_fresh_state(eng, model), data, 0)
 
 
-@pytest.mark.parametrize("algo", list_algorithms())
-def test_fused_kernel_path_matches_reference(algo):
+# an MLP whose 69,804 parameters pass one fed_direction block (65,536) and
+# are no multiple of it: the kernel path's plane aligns to 131,072
+LONG_PLANE = (8, 300, 220, 4)
+
+
+@pytest.mark.parametrize("algo,widths", [
+    *(pytest.param(a, (8, 16, 4), id=a) for a in list_algorithms()),
+    *(pytest.param(a, LONG_PLANE, id=f"{a}-long") for a in ("fedcm", "scaffold", "feddyn")),
+])
+def test_fused_kernel_path_matches_reference(algo, widths):
     """Flat engine + Pallas kernels (fed_direction local steps, fused
     server fold-row passes + pure post-steps) vs the unfused jnp flat
-    path — for EVERY registered algorithm (the registry parametrizes)."""
-    cfg, eng, data, model = _setup(algo)
+    path — for EVERY registered algorithm (the registry parametrizes),
+    and on a plane past one direction block that the kernel path aligns."""
+    cfg, eng, data, model = _setup(algo, widths)
     engk = FederatedEngine(replace(cfg, use_fused_kernel=True), eng.loss_fn, batch_size=8)
     s_ref, m_ref = eng.run_rounds(_fresh_state(eng, model), data, 3)
     s_k, m_k = engk.run_rounds(_fresh_state(engk, model), data, 3)

@@ -14,16 +14,22 @@ the compiles (an entry compiled for a described chip cannot be read back
 without one).
 """
 import os
+import re
 
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro import kernels
+from repro.configs.base import FedConfig, ModelConfig
+from repro.core import FederatedEngine, FlatSpec
 from repro.kernels.fed_direction.kernel import fed_direction_flat
 from repro.kernels.server_update.kernel import dequant_update_flat, server_update_flat
 from repro.kernels.server_update.ops import _auto_block
+from repro.models import build_model, federated_lm_loss
+from repro.utils.compat import device_mesh
 
 # llama3.2-1b at its published widths, 2 layers, an eighth of the vocab:
 # the Phase B client of chip_smoke.py (an odd length: the ragged tail pads)
@@ -107,3 +113,58 @@ def test_dequant_fold_compiles(one_chip, wire):
 
     hlo = _hlo_of(fold, q, scale, wn, plane, plane, coefs)
     assert "tpu_custom_call" in hlo
+
+
+# a one-layer LM client that compiles in seconds; its 110,784 parameters
+# are no multiple of either kernel's block
+TINY_LM = ModelConfig(name="tiny-lm", family="dense", n_layers=1, d_model=64,
+                      n_heads=2, n_kv_heads=1, head_dim=32, d_ff=256,
+                      vocab_size=512, mlp_type="gelu", dtype="bfloat16",
+                      param_dtype="float32")
+KERNEL_SCOPES = ("jit(fed_direction_flat)", "jit(server_update_flat)")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%((?:pad|slice|copy)[\w.\-]*) = \w+\[([\d,]*)\]")
+
+
+def _plane_copies(hlo: str, min_elems: int):
+    """pad, slice and copy instructions (fused or not) of at least
+    ``min_elems`` elements whose ``op_name`` lies under a kernel's jit."""
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not (m and op and any(s in op.group(1) for s in KERNEL_SCOPES)):
+            continue
+        elems = 1
+        for d in filter(None, m.group(2).split(",")):
+            elems *= int(d)
+        if elems >= min_elems:
+            found.append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("shards", [1, 4], ids=["one-chip", "mesh-2x2"])
+def test_kernel_round_copies_no_plane(topo, monkeypatch, shards):
+    """The kernel-path round of an LM client whose parameter count is not
+    aligned: the engine lays the plane out at the kernels' block length,
+    so no launch pads its operands or slices its outputs, on one chip or
+    in the scattered fold's column chunks.  The (C, 128) lane padding of
+    the cohort weights is not a plane and is not counted."""
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    model = build_model(TINY_LM)
+    cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=shards, local_steps=2,
+                    participation="fixed", use_fused_kernel=True)
+    if shards > 1:
+        mesh = device_mesh(topo.devices[:shards], ("clients",))
+        where = NamedSharding(mesh, PartitionSpec())
+    else:
+        mesh, where = None, SingleDeviceSharding(topo.devices[0])
+    eng = FederatedEngine(cfg, federated_lm_loss(model), batch_size=2, cohort_mesh=mesh)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(eng.init, params, jax.random.PRNGKey(1))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where), state)
+    tokens = jax.ShapeDtypeStruct((4, 8, 64), jnp.int32, sharding=where)
+    hlo = eng._run_rounds.lower(state, tokens, tokens, n_rounds=1).compile().as_text()
+    P = FlatSpec.from_tree(params).size
+    assert P % 1024 and hlo.count("tpu_custom_call") == 2
+    assert _plane_copies(hlo, P // shards) == []
