@@ -13,6 +13,22 @@ from typing import Dict, Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnRope:
+    """YaRN scaling of rotary embeddings, as HF ``rope_type: "yarn"``
+    defines it: each frequency blends its interpolated (÷ ``factor``) and
+    extrapolated value along a linear ramp between the dimensions whose
+    wavelengths fit ``beta_fast`` and ``beta_slow`` turns in
+    ``original_max_position_embeddings``; ``attention_factor`` multiplies
+    cos and sin."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | encdec | vlm | audio
@@ -27,6 +43,8 @@ class ModelConfig:
     # --- attention variants ---
     use_rope: bool = True
     rope_theta: float = 10000.0
+    # YaRN scaling of the full-attention layers' RoPE
+    rope_yarn: Optional[YarnRope] = None
     qk_norm: bool = False
     sliding_window: Optional[int] = None  # window for "local" attention layers
     # (n_local, n_global) per repeating period; None = all-global.
@@ -37,9 +55,14 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0  # routed experts: the router's outputs
+    # experts this layer holds, ids [0, n_experts_held): its share of an
+    # expert-parallel deployment.  0 = all of them
+    n_experts_held: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    # choices over capacity_factor × the mean load of an expert are
+    # dropped; None = dropless (every choice of a held expert is computed)
+    capacity_factor: Optional[float] = 1.25
     moe_every: int = 1  # a layer is MoE iff (layer_idx % moe_every == moe_every-1)
     shared_expert: bool = False
     router_z_loss: float = 1e-3
@@ -80,6 +103,10 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def ssm_heads(self) -> int:
@@ -150,7 +177,7 @@ class ModelConfig:
             for i in range(layers):
                 n += attn_params()
                 if self.is_moe_layer(i):
-                    e = self.n_experts
+                    e = self.experts_held
                     if active_only:
                         e = self.top_k + (1 if self.shared_expert else 0)
                     n += e * mlp_params(self.d_ff) + D * self.n_experts  # + router
@@ -425,6 +452,7 @@ ARCH_IDS = [
     "gemma3-12b",
     "chameleon-34b",
     "mamba2-1.3b",
+    "mellum2-12b-a2.5b",
 ]
 
 _MODULE_FOR: Dict[str, str] = {
@@ -438,6 +466,7 @@ _MODULE_FOR: Dict[str, str] = {
     "gemma3-12b": "gemma3_12b",
     "chameleon-34b": "chameleon_34b",
     "mamba2-1.3b": "mamba2_1_3b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
 
@@ -490,6 +519,8 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         updates["n_experts"] = min(cfg.n_experts, 4)
         updates["top_k"] = min(cfg.top_k, 2)
         updates["moe_every"] = min(cfg.moe_every, 2)
+        if cfg.n_experts_held:  # keep a share: half of the routed experts
+            updates["n_experts_held"] = min(cfg.n_experts_held, updates["n_experts"] // 2)
     if cfg.family in ("ssm", "hybrid"):
         updates["ssm_state"] = min(cfg.ssm_state, 16)
         updates["ssm_head_dim"] = 32

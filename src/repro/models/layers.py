@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnRope
 from repro.utils.compat import shard_map
 
 # ----------------------------------------------------------------------
@@ -83,15 +83,37 @@ def init_rmsnorm(d: int, dtype=jnp.float32):
 # ----------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float):
+def rope_frequencies(head_dim: int, theta: float, yarn: Optional[YarnRope] = None):
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta**exponents)  # (hd/2,)
+    freqs = 1.0 / (theta**exponents)  # (hd/2,)
+    if yarn is None:
+        return freqs
+    low, high = yarn_ramp(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (B, S, H, hd); positions: (S,) or (B, S) int32."""
+def yarn_ramp(head_dim: int, theta: float, yarn: YarnRope) -> Tuple[float, float]:
+    """YaRN's ramp ends (HF ``truncate``: floor and ceil): below ``low`` a
+    dimension's wavelength fits more than ``beta_fast`` turns in the
+    original context and keeps its frequency; from ``high`` on, fewer
+    than ``beta_slow``, and it is interpolated (÷ factor)."""
+
+    def dim(turns):
+        ctx = yarn.original_max_position_embeddings
+        return head_dim * math.log(ctx / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), head_dim - 1)
+    return low, (high + 0.001 if high == low else high)
+
+
+def apply_rope(x, positions, theta: float, yarn: Optional[YarnRope] = None):
+    """x: (B, S, H, hd); positions: (S,) or (B, S) int32.  With ``yarn``
+    the frequencies are YaRN's and cos and sin carry its attention
+    factor."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)  # (hd/2,)
+    freqs = rope_frequencies(hd, theta, yarn)  # (hd/2,)
     pos = positions.astype(jnp.float32)
     angles = pos[..., None] * freqs  # (S, hd/2) or (B, S, hd/2)
     if angles.ndim == 2:  # (S, hd/2) -> (1, S, 1, hd/2)
@@ -99,6 +121,8 @@ def apply_rope(x, positions, theta: float):
     else:  # (B, S, hd/2) -> (B, S, 1, hd/2)
         angles = angles[:, :, None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -310,8 +334,10 @@ def self_attention(
         q = rmsnorm(q, params["q_norm"])
         k = rmsnorm(k, params["k_norm"])
     if cfg.use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        # RoPE by layer type: YaRN on the full layers only
+        yarn = cfg.rope_yarn if _static_true(is_global) else None
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     if parallel is not None and cache is None:
         # train/prefill: shard heads over "model" (TP attention).  In DECODE
         # the cache is sequence-sharded over "model"; head-sharding q forces
@@ -511,10 +537,12 @@ def mlp(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelContext] = No
 
 
 def init_moe(rng, cfg: ModelConfig, dtype=jnp.float32):
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router routes over all ``n_experts``; the expert stacks hold
+    the layer's ``experts_held``."""
+    D, F, E = cfg.d_model, cfg.d_ff, cfg.experts_held
     ks = jax.random.split(rng, 5)
     p = {
-        "router": dense_init(ks[0], (D, E), dtype=jnp.float32),  # router kept f32
+        "router": dense_init(ks[0], (D, cfg.n_experts), dtype=jnp.float32),  # router kept f32
         "w_gate": dense_init(ks[1], (E, D, F), in_axis_size=D, dtype=dtype),
         "w_up": dense_init(ks[2], (E, D, F), in_axis_size=D, dtype=dtype),
         "w_down": dense_init(ks[3], (E, F, D), in_axis_size=F, dtype=dtype),
@@ -525,32 +553,39 @@ def init_moe(rng, cfg: ModelConfig, dtype=jnp.float32):
 
 
 def _router(params, x, cfg: ModelConfig):
-    """Returns (gates (T,k), experts (T,k), probs (T,E), aux_loss scalar)."""
+    """Returns (gates (T,k), experts (T,k), probs (T,E), aux_loss scalar).
+    Logits in f32 over all routed experts; the top-k gates renormalised."""
     T = x.shape[0]
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), params["router"])
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, experts = jax.lax.top_k(probs, cfg.top_k)  # (T,k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    # aux losses: load-balance (Switch) + router z-loss
-    me = probs.mean(axis=0)  # (E,)
-    ce = jnp.zeros((cfg.n_experts,), jnp.float32)
-    ce = ce.at[experts.reshape(-1)].add(1.0) / (T * cfg.top_k)
-    lb = cfg.n_experts * jnp.sum(me * ce) * cfg.load_balance_loss
-    z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2) * cfg.router_z_loss
-    return gates, experts, probs, lb + z
+    # aux losses: load-balance (Switch) + router z-loss, where configured
+    aux = jnp.float32(0.0)
+    if cfg.load_balance_loss:
+        me = probs.mean(axis=0)  # (E,)
+        ce = jnp.zeros((cfg.n_experts,), jnp.float32)
+        ce = ce.at[experts.reshape(-1)].add(1.0) / (T * cfg.top_k)
+        aux = aux + cfg.n_experts * jnp.sum(me * ce) * cfg.load_balance_loss
+    if cfg.router_z_loss:
+        aux = aux + jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2) * cfg.router_z_loss
+    return gates, experts, probs, aux
 
 
 def moe_ref(params, x, *, cfg: ModelConfig):
-    """Dense reference MoE: every expert computed on every token, masked.
+    """Dense reference MoE: every held expert computed on every token,
+    masked by its gate (0 where the token did not choose it).
 
     O(T*E*D*F) — only for reduced configs / oracles.  Returns (out, aux).
     """
     B, S, D = x.shape
     xt = x.reshape(-1, D)
     gates, experts, _, aux = _router(params, xt, cfg)
-    # combine weight per expert per token: (T, E)
+    # combine weight per expert per token: (T, E), of which the held ones
     comb = jnp.zeros((xt.shape[0], cfg.n_experts), x.dtype)
     comb = comb.at[jnp.arange(xt.shape[0])[:, None], experts].add(gates.astype(x.dtype))
+    comb = comb[:, : params["w_gate"].shape[0]]
 
     def one_expert(wg, wu, wd):
         h = jax.nn.silu(xt @ wg.astype(x.dtype)) * (xt @ wu.astype(x.dtype))
@@ -568,22 +603,22 @@ def moe_capacity(cfg: ModelConfig, tokens: int) -> int:
     return max(8, -(-c // 8) * 8)  # round up to 8 for TPU-friendly shapes
 
 
-def moe_dropping(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelContext] = None):
-    """Capacity-based scatter/gather MoE (token-dropping, GShard-style slots,
-    but WITHOUT the (T,E,C) one-hot dispatch tensor — slots are computed with
-    a (T*k, E) cumsum and a scatter-add, which is what keeps dbrx-scale
-    (E=16, top-4) feasible).
+def moe_block(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelContext] = None):
+    """Sparse-expert FFN over the experts the layer holds: dropless
+    (``moe_dropless``) where ``cfg.capacity_factor`` is None, else with
+    capacity slots (``_dropping_local``).
 
     With a mesh, runs under shard_map: tokens stay on their (pod,data) shard,
-    experts are sharded over the model axis; each model shard computes its
-    experts for the local tokens and the partial outputs are psum'd over the
-    model axis (one (T_local, D) all-reduce per MoE layer — the same volume
-    as a tensor-parallel MLP).
+    the held experts are sharded over the model axis; each model shard
+    computes its experts for the local tokens and the partial outputs are
+    psum'd over the model axis (one (T_local, D) all-reduce per MoE layer —
+    the same volume as a tensor-parallel MLP).
     """
     B, S, D = x.shape
+    local = moe_dropless if cfg.capacity_factor is None else _dropping_local
 
     if parallel is None:
-        out, aux = _moe_local(params, x.reshape(-1, D), cfg=cfg, e_lo=0)
+        out, aux = local(params, x.reshape(-1, D), cfg=cfg, e_lo=0)
         out = out.reshape(B, S, D)
         if cfg.shared_expert:
             out = out + mlp(params["shared"], x, cfg=cfg)
@@ -592,9 +627,9 @@ def moe_dropping(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelCont
     mesh = parallel.mesh
     maxis = parallel.model_axis
     msize = parallel.model_size
-    e_per = cfg.n_experts // msize
-    assert e_per * msize == cfg.n_experts, (
-        f"n_experts={cfg.n_experts} must divide model axis {msize}"
+    e_per = cfg.experts_held // msize
+    assert e_per * msize == cfg.experts_held, (
+        f"held experts={cfg.experts_held} must divide model axis {msize}"
     )
 
     def body(xl, router, wg, wu, wd):
@@ -602,7 +637,7 @@ def moe_dropping(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelCont
         j = jax.lax.axis_index(maxis)
         xt = xl.reshape(-1, D)
         p_local = {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}
-        out, aux = _moe_local(p_local, xt, cfg=cfg, e_lo=j * e_per)
+        out, aux = local(p_local, xt, cfg=cfg, e_lo=j * e_per)
         out = jax.lax.psum(out, maxis)
         aux = jax.lax.psum(aux, maxis) / msize
         return out.reshape(xl.shape), aux
@@ -623,9 +658,12 @@ def moe_dropping(params, x, *, cfg: ModelConfig, parallel: Optional[ParallelCont
     return out, aux
 
 
-def _moe_local(params, xt, *, cfg: ModelConfig, e_lo):
+def _dropping_local(params, xt, *, cfg: ModelConfig, e_lo):
     """Tokens xt (T, D) through E_local experts starting at ``e_lo`` (may be
-    a traced axis_index) with capacity slots.
+    a traced axis_index) with capacity slots (token-dropping, GShard-style
+    slots, but WITHOUT the (T,E,C) one-hot dispatch tensor — slots are
+    computed with a (T*k, E) cumsum and a scatter-add, which is what keeps
+    dbrx-scale (E=16, top-4) feasible).
 
     params["w_*"] hold exactly E_local experts (static, from the leaf
     shape).  Routing decisions are computed over ALL E experts (router is
@@ -661,3 +699,102 @@ def _moe_local(params, xt, *, cfg: ModelConfig, e_lo):
     gathered = eout[slot] * gates.reshape(-1)[:, None].astype(xt.dtype)  # (T*k, D)
     out = jnp.zeros((T, D), xt.dtype).at[tok_idx].add(gathered)
     return out, aux
+
+
+# ----------------------------------------------------------------------
+# dropless experts: grouped matmuls over rows sorted by expert
+# ----------------------------------------------------------------------
+
+
+def _unbatched(fn):
+    """``fn`` under ``vmap`` as one call per batch element: the TPU's
+    grouped matmul (``ragged_dot``) takes no batch dimension, and the
+    federated engine maps each client's step over its cohort."""
+    fn = jax.custom_batching.custom_vmap(fn)
+
+    @fn.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        outs = [fn(*(a[i] if b else a for a, b in zip(args, in_batched)))
+                for i in range(axis_size)]
+        return jnp.stack(outs), True
+
+    return fn
+
+
+@_unbatched
+def _rows_by_group(x, w, group_sizes):
+    """(M, K) rows in group order × (G, K, N) -> (M, N)."""
+    return jax.lax.ragged_dot(x, w, group_sizes)
+
+
+_CONTRACT_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[])
+
+
+@_unbatched
+def _per_group(x, dy, group_sizes):
+    """Per group g: x[rows of g]^T @ dy[rows of g]: (M, K), (M, N) -> (G, K, N)."""
+    return jax.lax.ragged_dot_general(x, dy, group_sizes, _CONTRACT_ROWS)
+
+
+def _grouped_rows_only(y, group_sizes):
+    """Rows past the last group are no group's: zero, whatever the
+    kernel left in them."""
+    rows = jnp.arange(y.shape[0])
+    return jnp.where((rows < jnp.sum(group_sizes))[:, None], y, jnp.zeros((), y.dtype))
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, group_sizes):
+    """Row i of group g times w[g]: x (M, K) sorted by group, w (G, K, N),
+    group_sizes (G,) summing to at most M; rows past the groups give 0.
+    On TPU ``ragged_dot`` lowers to a Mosaic kernel whose grid visits only
+    the row tiles that hold grouped rows, so the work follows the rows
+    routed here, not M."""
+    return _grouped_rows_only(_rows_by_group(x, w, group_sizes), group_sizes)
+
+
+def _grouped_matmul_fwd(x, w, group_sizes):
+    return grouped_matmul(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _grouped_matmul_bwd(res, dy):
+    x, w, group_sizes = res
+    dx = _grouped_rows_only(_rows_by_group(dy, jnp.swapaxes(w, 1, 2), group_sizes), group_sizes)
+    return dx, _per_group(x, dy, group_sizes).astype(w.dtype), None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def moe_dropless(params, xt, *, cfg: ModelConfig, e_lo):
+    """Tokens xt (T, D) through the E_local held experts [e_lo, e_lo +
+    E_local) with no capacity: every (token, choice) routed to a held
+    expert is computed, in the worst case all T*k of them; choices of
+    absent experts add nothing.  The rows are sorted by expert and run as
+    grouped matmuls (SwiGLU), then scattered back weighted by their gates.
+
+    Scopes: ``moe.dispatch`` (router, top-k, sort, gather, weighted
+    scatter) and ``moe.experts`` (the grouped matmuls and the SiLU product).
+    """
+    T, D = xt.shape
+    E_local = params["w_gate"].shape[0]
+    dt = xt.dtype
+    with jax.named_scope("moe.dispatch"):
+        gates, experts, _, aux = _router(params, xt, cfg)  # (T,k)
+        group = experts.reshape(-1) - e_lo  # (T*k,)
+        held = (group >= 0) & (group < E_local)
+        group = jnp.where(held, group, E_local)  # absent experts' choices sort last
+        order = jnp.argsort(group)
+        token = order // cfg.top_k
+        sizes = jnp.zeros((E_local,), jnp.int32).at[group].add(1, mode="drop")
+        weight = jnp.where(held, gates.reshape(-1), 0.0)[order]
+        rows = xt[token]
+    with jax.named_scope("moe.experts"):
+        h = jax.nn.silu(grouped_matmul(rows, params["w_gate"].astype(dt), sizes))
+        h = h * grouped_matmul(rows, params["w_up"].astype(dt), sizes)
+        y = grouped_matmul(h, params["w_down"].astype(dt), sizes)
+    with jax.named_scope("moe.dispatch"):
+        out = jnp.zeros((T, D), jnp.float32).at[token].add(y.astype(jnp.float32) * weight[:, None])
+    return out.astype(dt), aux

@@ -33,8 +33,7 @@ from repro.models.layers import (
     init_moe,
     init_rmsnorm,
     mlp,
-    moe_dropping,
-    moe_ref,
+    moe_block,
     rmsnorm,
     self_attention,
     shard,
@@ -181,8 +180,7 @@ def _apply_slot(
     )
     h = h + attn_out
     if slot.is_moe:
-        moe_fn = moe_dropping  # ref for tests comes via moe_ref in oracles
-        y, moe_aux = moe_fn(p["moe"], rmsnorm(h, p["norm2"]), cfg=cfg, parallel=parallel)
+        y, moe_aux = moe_block(p["moe"], rmsnorm(h, p["norm2"]), cfg=cfg, parallel=parallel)
         aux = aux + moe_aux
     elif "mlp" in p:
         y = mlp(p["mlp"], rmsnorm(h, p["norm2"]), cfg=cfg, parallel=parallel)

@@ -55,6 +55,7 @@ def test_full_configs_match_assignment():
         "gemma3-12b": (48, 3840, 16, 8, 15360, 262144),
         "chameleon-34b": (48, 8192, 64, 8, 22016, 65536),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),
+        "mellum2-12b-a2.5b": (28, 2304, 32, 4, 896, 98304),
     }
     for arch, (L, D, H, Hkv, F, V) in expect.items():
         cfg = get_config(arch)
@@ -74,6 +75,10 @@ def test_full_configs_match_assignment():
     assert get_config("gemma3-12b").local_global_pattern == (5, 1)
     assert get_config("qwen3-14b").qk_norm
     assert get_config("seamless-m4t-large-v2").is_encoder_decoder
+    mellum = get_config("mellum2-12b-a2.5b")
+    assert (mellum.n_experts, mellum.n_experts_held, mellum.top_k) == (64, 8, 8)
+    assert mellum.capacity_factor is None and mellum.local_global_pattern == (3, 1)
+    assert mellum.rope_yarn.factor == 16 and mellum.sliding_window == 1024
 
 
 def test_forward_shapes_and_finiteness(arch_setup):

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config, reduced
 from repro.models import build_model
-from repro.models.layers import attend_blocked, attend_direct, moe_dropping, moe_ref
+from repro.models.layers import attend_blocked, attend_direct, moe_block, moe_ref
 
 RNG = np.random.default_rng(0)
 
@@ -111,7 +111,7 @@ def test_moe_dropping_matches_ref_at_high_capacity():
 
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jnp.asarray(RNG.normal(size=(2, 12, cfg.d_model)), jnp.float32)
-    out_d, aux_d = moe_dropping(p, x, cfg=cfg)
+    out_d, aux_d = moe_block(p, x, cfg=cfg)
     out_r, aux_r = moe_ref(p, x, cfg=cfg)
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_r), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(float(aux_d), float(aux_r), rtol=1e-5)
@@ -126,7 +126,7 @@ def test_moe_capacity_drops_tokens_but_stays_finite():
 
     p = init_moe(jax.random.PRNGKey(0), cfg)
     x = jnp.asarray(RNG.normal(size=(2, 64, cfg.d_model)), jnp.float32)
-    out, aux = moe_dropping(p, x, cfg=cfg)
+    out, aux = moe_block(p, x, cfg=cfg)
     assert np.all(np.isfinite(np.asarray(out)))
     # dropped tokens ⇒ output differs from the no-drop reference
     out_r, _ = moe_ref(p, x, cfg=cfg)

@@ -13,6 +13,7 @@ worker imports this file.  The persistent compilation cache is off around
 the compiles (an entry compiled for a described chip cannot be read back
 without one).
 """
+import base64
 import os
 import re
 
@@ -29,6 +30,7 @@ from repro.kernels.fed_direction.kernel import fed_direction_flat
 from repro.kernels.server_update.kernel import dequant_update_flat, server_update_flat
 from repro.kernels.server_update.ops import _auto_block
 from repro.models import build_model, federated_lm_loss
+from repro.models.layers import init_moe, moe_block
 from repro.utils.compat import device_mesh
 
 # llama3.2-1b at its published widths, 2 layers, an eighth of the vocab:
@@ -168,3 +170,47 @@ def test_kernel_round_copies_no_plane(topo, monkeypatch, shards):
     P = FlatSpec.from_tree(params).size
     assert P % 1024 and hlo.count("tpu_custom_call") == 2
     assert _plane_copies(hlo, P // shards) == []
+
+
+# Mellum2's expert layer at its published widths, 8 of 64 experts held,
+# top-8 over 2,048 tokens: 16,384 (token, choice) rows
+MOE = ModelConfig(name="mellum2-experts", family="moe", n_layers=1, d_model=2304, n_heads=32,
+                  n_kv_heads=4, head_dim=128, d_ff=896, vocab_size=512, n_experts=64,
+                  n_experts_held=8, top_k=8, capacity_factor=None, router_z_loss=0.0,
+                  load_balance_loss=0.0, dtype="bfloat16")
+_GRID = re.compile(r"iteration_bounds = array<i64: ([^>]*)>")
+DYNAMIC = -(2**63)  # a grid extent the kernel reads at run time
+
+
+def _grouped_kernel_grids(hlo: str):
+    """The grid of each grouped-matmul kernel (``ragged-dot-*``), read from
+    its Mosaic body."""
+    grids = []
+    for line in hlo.splitlines():
+        if re.match(r"\s*(?:ROOT )?%ragged-dot-none", line):
+            body = base64.b64decode(re.search(r'"body":"([^"]+)"', line).group(1)).decode("latin1")
+            grids.append([int(v) for v in _GRID.search(body).group(1).split(",")])
+    return grids
+
+
+def test_dropless_experts_compile_to_grouped_kernels(one_chip):
+    """The dropless layer's forward and backward, mapped over a cohort of
+    two clients as the engine maps them: every grouped matmul (per client 3
+    forward, 3 row gradients, 3 weight gradients) compiles to a Mosaic
+    kernel whose grid takes its extent over the rows from the group sizes
+    at run time, so the work follows the rows routed to the held experts,
+    not the T × top_k rows the buffers are sized for."""
+
+    def shaped(a, lead=(2,)):
+        return jax.ShapeDtypeStruct(lead + a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(shaped, jax.eval_shape(lambda k: init_moe(k, MOE), jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((2, 1, 2048, MOE.d_model), jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(jnp.square(moe_block(p, x, cfg=MOE)[0].astype(jnp.float32)))
+
+    grids = _grouped_kernel_grids(_hlo_of(jax.vmap(jax.grad(loss, argnums=(0, 1))), params, x))
+    assert len(grids) == 2 * 9
+    assert all(g.count(DYNAMIC) == 1 for g in grids), grids
+
