@@ -538,8 +538,9 @@ def flat_client_update(
 
     ``cfg.use_fused_kernel`` flips the scan onto the flat ``(P,)`` carry
     instead: the ``fed_direction`` kernel consumes flat buffers directly
-    (no per-step concatenate/split — the loss unravels the plane by
-    slicing, which fuses on TPU where this path is aimed) and the outputs
+    (the loss unravels the plane by slicing, which fuses on TPU where
+    this path is aimed, and its gradient returns to the plane as the
+    view's backward, one concatenate of the leaf gradients) and the outputs
     ARE ``(P,)`` planes, giving the ``(C, P)`` delta plane the fused
     ``server_update`` kernel wants for free.
     """

@@ -61,11 +61,9 @@ from repro.utils.trees import ravel_leaves, split_flat
 
 # Name scope of every conversion between the plane and its leaf views
 # (``FlatSpec.ravel`` / ``unravel``).  It reaches the compiled program's
-# ``op_name`` metadata, through autodiff as
-# ``transpose(jvp(fedcm.plane_view))`` on the gradient's pads and adds back
-# into the plane, so a device trace can attribute the plane's copies.  A
-# fusion counts by its root: where XLA fuses the gradient's accumulation
-# into the weight-decay add, that fusion carries ``fedcm.local_steps``.
+# ``op_name`` metadata, the gradient's return into the plane included
+# (``unravel``'s backward is a ``ravel``), so a device trace can attribute
+# the plane's copies.
 PLANE_VIEW_SCOPE = "fedcm.plane_view"
 
 
@@ -146,11 +144,29 @@ class FlatSpec:
         Leaf dtypes are restored from the table (pass ``dtype`` to override,
         e.g. a uniform momentum dtype).  Under jit the slices fuse into
         their consumers — no per-step copy.
+
+        The view's transpose is ``ravel``: the gradient returns to the plane
+        as ONE concatenate of the leaf cotangents (cast to the plane's
+        dtype, the zero tail last), where autodiff of the slices would pad
+        each leaf's cotangent to the whole plane and sum the pads — a pass
+        whose cost grows with leaves × P'.  The values are the same: each
+        element is its leaf's cotangent either way (up to the sign of a
+        zero).
         """
         dtypes = [dtype or l.dtype for l in self.leaves]
-        with jax.named_scope(PLANE_VIEW_SCOPE):
-            leaves = split_flat(flat, [l.shape for l in self.leaves], dtypes)
-        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+        plane_dtype, batch_dims = flat.dtype, flat.ndim - 1
+
+        @jax.custom_vjp
+        def view(flat):
+            with jax.named_scope(PLANE_VIEW_SCOPE):
+                leaves = split_flat(flat, [l.shape for l in self.leaves], dtypes)
+            return jax.tree_util.tree_unflatten(self.treedef, leaves)
+
+        def view_bwd(_, ct):
+            return (self.ravel(ct, dtype=plane_dtype, batch_dims=batch_dims),)
+
+        view.defvjp(lambda flat: (view(flat), None), view_bwd)
+        return view(flat)
 
     def view_leaf(self, flat: jax.Array, key: Union[int, str], dtype=None):
         """One leaf of the plane by index or key path, without the tree."""

@@ -11,6 +11,9 @@
 * The kernel path's aligned plane: a zero tail that ``ravel`` writes,
   ``unravel`` ignores and every algorithm keeps exactly zero, while
   payload accounting charges the leaves alone.
+* The gradient's return into the plane: ``unravel``'s transpose is one
+  concatenate, and gives the numbers the slices' own transpose (a pad of
+  each leaf to the plane, summed) gives, down to the engine's planes.
 """
 from dataclasses import replace
 
@@ -24,6 +27,7 @@ from repro.configs.base import CompressionConfig, FedConfig
 from repro.core import FederatedEngine, FlatSpec, list_algorithms
 from repro.data import FederatedData, make_synthetic_classification
 from repro.models.small import classification_loss, mlp_classifier
+from repro.utils.trees import split_flat
 
 RNG = np.random.default_rng(0)
 
@@ -266,6 +270,83 @@ def test_flat_engine_bf16_mixed_param_tree():
         jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), newt.params),
         atol=2e-2, rtol=2e-2,
     )
+
+
+# ----------------------------------------------------------------------
+# the gradient through the plane view
+# ----------------------------------------------------------------------
+
+
+def _sliced_view(spec, flat, dtype=None):
+    """The view as plain slices, whose autodiff transpose pads each leaf's
+    cotangent to the whole plane and sums the pads: the oracle of
+    ``FlatSpec.unravel``'s own transpose."""
+    dtypes = [dtype or l.dtype for l in spec.leaves]
+    leaves = split_flat(flat, [l.shape for l in spec.leaves], dtypes)
+    return jax.tree_util.tree_unflatten(spec.treedef, leaves)
+
+
+def _leaf_loss(tree):
+    """Weighs every leaf differently; leaf "unused" gets a zero cotangent."""
+    total = 0.0
+    for i, (path, leaf) in enumerate(jax.tree_util.tree_leaves_with_path(tree)):
+        if "unused" not in jax.tree_util.keystr(path):
+            total += jnp.sum(jnp.sin(leaf.astype(jnp.float32) * (i + 1)) * leaf)
+    return total
+
+
+_VIEW_CASES = {
+    "mixed": dict(lead=(), batch_dims=0, vmap=False, dtype=None),
+    "batch_dims": dict(lead=(3,), batch_dims=1, vmap=False, dtype=None),
+    "vmap": dict(lead=(3,), batch_dims=1, vmap=True, dtype=None),
+    "dtype-override": dict(lead=(), batch_dims=0, vmap=False, dtype=jnp.bfloat16),
+    "vmap-dtype-override": dict(lead=(2,), batch_dims=1, vmap=True, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_VIEW_CASES))
+def test_unravel_gradient_equals_the_slices_transpose(case):
+    """Leaves of mixed shape and dtype (f32, bf16, a scalar, one the loss
+    does not read) on a plane with a zero tail: ``jax.grad`` through
+    ``unravel`` equals the gradient through the plain slices exactly (only
+    the sign of a zero may differ, which ``==`` ignores), with leading
+    batch axes, under ``vmap`` and with the ``dtype=`` override."""
+    c = _VIEW_CASES[case]
+    per_leaf = {**_mixed_tree(), "unused": jnp.ones((4, 2), jnp.float32)}
+    spec = FlatSpec.from_tree(per_leaf).aligned(64)
+    assert spec.plane_size > spec.size
+    tree = jax.tree_util.tree_map(
+        lambda l: jnp.asarray(RNG.normal(size=c["lead"] + l.shape), l.dtype), per_leaf)
+    flat = spec.ravel(tree, batch_dims=c["batch_dims"])
+
+    def grad_of(view):
+        g = jax.grad(lambda f: _leaf_loss(view(f)))
+        return jax.vmap(g) if c["vmap"] else g
+
+    got = grad_of(lambda f: spec.unravel(f, dtype=c["dtype"]))(flat)
+    want = grad_of(lambda f: _sliced_view(spec, f, dtype=c["dtype"]))(flat)
+    assert got.shape == flat.shape and got.dtype == flat.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.any(np.asarray(got)[..., :spec.size] != 0)
+    np.testing.assert_array_equal(np.asarray(got)[..., spec.size:], 0.0)
+
+
+@pytest.mark.parametrize("algo", ["fedcm", "mimelite"])
+def test_kernel_path_planes_match_the_slices_transpose(algo, monkeypatch):
+    """A kernel-path ``run_rounds`` on a multi-leaf model with weight decay
+    (MimeLite adds a full-batch gradient) gives the planes, momentum and
+    losses that the view's plain slices give, bit for bit."""
+    runs = []
+    for view in (FlatSpec.unravel, _sliced_view):
+        monkeypatch.setattr(FlatSpec, "unravel", view)
+        _, eng, data, model = _setup(algo, use_fused_kernel=True, weight_decay=1e-3)
+        runs.append(eng.run_rounds(_fresh(eng, model), data, N_ROUNDS))
+    (got, m_got), (want, m_want) = runs
+    for a, b in ((got.params, want.params), (got.server.momentum, want.server.momentum)):
+        assert len(jax.tree_util.tree_leaves(a)) == 4
+        for la, lb in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    np.testing.assert_array_equal(np.asarray(m_got.loss), np.asarray(m_want.loss))
 
 
 # ----------------------------------------------------------------------

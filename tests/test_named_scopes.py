@@ -2,11 +2,10 @@
 
 A device trace attributes the round program's time to layers by three
 ``jax.named_scope`` names in each operation's ``op_name``: the plane
-views (``FlatSpec.ravel``/``unravel`` and, through autodiff, the
-gradient's pads and adds back into the plane; a fusion counts by its
-root, so where XLA fuses that accumulation into the weight-decay add it
-counts to the local steps), each client's loss, gradient and finalize,
-and the round close with the round's metric norms.  The innermost scope
+views (``FlatSpec.ravel``/``unravel``, and the gradient's return into the
+plane, which is ``unravel``'s backward: one concatenate of the leaf
+gradients), each client's loss, gradient and finalize, and the round
+close with the round's metric norms.  The innermost scope
 wins: an operation counts to the first of ``ORDER`` its own ``op_name``
 holds.  These tests compile the flat round on the kernel path (Pallas in
 interpret mode) at a tiny size and read the ``op_name``s from the
@@ -92,12 +91,18 @@ def test_every_scope_reaches_the_round_program(program):
 
 
 def test_gradient_returns_to_the_plane_under_plane_view(program):
-    back = f"transpose(jvp({PLANE_VIEW}))"
-    steps = [op_name for _, _, _, op_name in program if LOCAL_STEPS in op_name]
-    pads = [n for n in steps if n.endswith("/pad")]
-    assert pads and all(back in n for n in pads), pads
-    assert any(n.endswith(f"{back}/add_any") for n in steps)
-    assert all(scope_of(n) == PLANE_VIEW for n in steps if back in n)
+    """The gradient returns to the plane as one concatenate of the leaf
+    gradients into a ``(C, P')`` plane, under the plane view; no op of
+    the plane view in the local steps pads a leaf to the plane."""
+    eng, st, *_ = _engine()
+    plane = f",{eng._flat_spec(st.params).plane_size}]"
+    back = [(opcode, shape, op_name) for _, opcode, shape, op_name in program
+            if LOCAL_STEPS in op_name and "transpose(" in op_name and PLANE_VIEW in op_name]
+    assert any(opcode == "concatenate" and plane in shape for opcode, shape, _ in back), back
+    assert all(scope_of(n) == PLANE_VIEW for _, _, n in back)
+    view_ops = {opcode for _, opcode, _, op_name in program
+                if LOCAL_STEPS in op_name and scope_of(op_name) == PLANE_VIEW}
+    assert view_ops and "pad" not in view_ops, view_ops
 
 
 def test_local_step_matmuls_count_to_local_steps(program):

@@ -14,6 +14,7 @@ the compiles (an entry compiled for a described chip cannot be read back
 without one).
 """
 import base64
+import math
 import os
 import re
 
@@ -31,6 +32,7 @@ from repro.kernels.server_update.kernel import dequant_update_flat, server_updat
 from repro.kernels.server_update.ops import _auto_block
 from repro.models import build_model, federated_lm_loss
 from repro.models.layers import init_moe, moe_block
+from repro.models.small import classification_loss, mlp_classifier
 from repro.utils.compat import device_mesh
 
 # llama3.2-1b at its published widths, 2 layers, an eighth of the vocab:
@@ -170,6 +172,56 @@ def test_kernel_round_copies_no_plane(topo, monkeypatch, shards):
     P = FlatSpec.from_tree(params).size
     assert P % 1024 and hlo.count("tpu_custom_call") == 2
     assert _plane_copies(hlo, P // shards) == []
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_FUSION = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) fusion\(.*calls=%([\w.\-]+)")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _fusion_pads(hlo: str, min_elems: int):
+    """The number of pads in each fusion whose output (its largest array)
+    has at least ``min_elems`` elements."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name:
+            bodies[name].append(line)
+    pads = []
+    for lines in bodies.values():
+        for line in lines:
+            m = _FUSION.match(line)
+            if m and max(math.prod(int(d) for d in filter(None, dims.split(",")))
+                         for dims in _ARRAY.findall(m.group(1))) >= min_elems:
+                pads.append(sum(" pad(" in body for body in bodies.get(m.group(2), [])))
+    return pads
+
+
+def test_gradient_returns_to_the_plane_without_a_pad_per_leaf(one_chip, monkeypatch):
+    """The kernel-path local steps of a 44-leaf client with weight decay,
+    under the cohort ``vmap`` and the K-step ``scan``: the gradient returns
+    to the plane as ``unravel``'s backward, one concatenate of the leaf
+    gradients, so no plane-sized fusion holds a pad for each leaf (autodiff
+    of the view's slices would sum one plane-sized pad per leaf)."""
+    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    model = mlp_classifier((8,) + (32,) * 21 + (4,))
+    cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=2, local_steps=2,
+                    participation="fixed", use_fused_kernel=True, weight_decay=1e-3)
+    eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=4)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(eng.init, params, jax.random.PRNGKey(1))
+    state = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), state)
+    cx = jax.ShapeDtypeStruct((4, 16, 8), jnp.float32, sharding=one_chip)
+    cy = jax.ShapeDtypeStruct((4, 16), jnp.int32, sharding=one_chip)
+    hlo = eng._run_rounds.lower(state, cx, cy, n_rounds=1).compile().as_text()
+    spec = FlatSpec.from_tree(params)
+    assert len(spec.leaves) == 44
+    pads = _fusion_pads(hlo, spec.size)
+    assert pads and max(pads) <= 1, pads
 
 
 # Mellum2's expert layer at its published widths, 8 of 64 experts held,
