@@ -80,8 +80,7 @@ def _measure_workload(name, dims, cohort, K, B, clients, rounds, alts, quiet,
     if not sweep:  # cheap workloads sweep every count; others baseline-vs-widest
         device_counts = [max(device_counts)] if device_counts else []
     cfg = FedConfig(algo="fedcm", num_clients=clients, cohort_size=cohort,
-                    local_steps=K, participation="fixed",
-                    use_fused_kernel=True)
+                    local_steps=K, participation="fixed")
     x, y, *_ = make_synthetic_classification(
         n_classes=10, dim=dims[0], n_train=cohort * 200, n_test=10
     )

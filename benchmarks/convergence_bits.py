@@ -52,7 +52,7 @@ def run_cell(algo: str, kind, rounds: int, seed: int = 0) -> dict:
         algo=algo, num_clients=N_CLIENTS, cohort_size=COHORT,
         local_steps=LOCAL_STEPS, alpha=0.1, eta_l=0.05, eta_g=1.0,
         participation="bernoulli", rounds=rounds, seed=seed,
-        use_fused_kernel=True, compression=comp,
+        compression=comp,
     )
     x_tr, y_tr, x_te, y_te = make_synthetic_classification(
         n_classes=N_CLASSES, dim=DIM, n_train=20_000, n_test=2_000, seed=seed)

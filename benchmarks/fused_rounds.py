@@ -12,10 +12,11 @@ same trajectory three ways:
   masked tensordot per leaf per uplink plane (including the zeros
   state/extra planes stateless algorithms still materialize), per-leaf
   server updates, per-leaf metric norms,
-* flat-fused (this PR's default): the same local-step scan, but every
-  round-scope reduction lands on ONE ravelled (P,) buffer — a single
-  contraction per uplink plane, a fused flat server step, flat norms, and
-  no zeros planes at all.
+* flat-fused (the default engine): the local steps carry ONE ravelled
+  (P,) buffer through the ``fed_direction`` kernel and every round-scope
+  reduction lands on it — the fused ``server_update`` fold, flat norms,
+  and no zeros planes at all.  On a CPU host the kernels run in the
+  Pallas interpreter.
 
 Three workloads, all in the artifact:
 
@@ -28,9 +29,7 @@ Three workloads, all in the artifact:
   shape is its faithful stand-in.  The acceptance bar (flat ≥ 1.3× the
   PR-1 tree path) is measured here.
 * ``paper_scaled`` (PR-1's original shape): 3-layer MLP, K=5, B=32 —
-  local-grad-bound; flat ≈ tree by construction (the local scan is the
-  same leaf-form code in both engines) and the number documents that the
-  refactor costs nothing where it cannot win.
+  local-grad-bound, where the plane has little to win.
 * ``async_pipeline``: the update-bound shape through the overlapping-cohort
   engine (``run_rounds_async``, ``scan_unroll=2`` — the ring boundary
   amortizes across an unrolled pair; the sync scan has no such boundary)
@@ -42,7 +41,7 @@ Three workloads, all in the artifact:
   shared 2-core container single ratios swing ±8%), so the mode is free
   until a multi-host mesh gives the overlap something to hide.
 * ``algo_sweep``: rounds/s for EVERY registered algorithm
-  (``repro.core.list_algorithms``) on the flat+kernel path — the per-PR
+  (``repro.core.list_algorithms``) on the flat engine — the per-PR
   record that each spec's declarative routing (direction row →
   ``fed_direction``, fold rows → ``server_update``, pure post-steps)
   actually executes, and what each costs relative to fedcm.  A spec that
@@ -279,12 +278,11 @@ def _measure_algo_sweep(rounds, quiet, dims=(32, 64, 64, 10), cohort=8, K=2, B=1
         "num_clients": 64, "cohort_size": cohort, "local_steps": K,
         "batch_size": B, "rounds": rounds,
         "model": f"mlp {len(dims) - 1} layers ({2 * (len(dims) - 1)} leaves)",
-        "path": "flat + fused kernels (use_fused_kernel=True)",
+        "path": "flat + fused kernels",
     }, "rounds_per_s": {}}
     for algo in list_algorithms():
         cfg = FedConfig(algo=algo, num_clients=64, cohort_size=cohort,
-                        local_steps=K, participation="fixed",
-                        use_fused_kernel=True)
+                        local_steps=K, participation="fixed")
         eng = FederatedEngine(cfg, loss_fn, batch_size=B)
         data = FederatedData(x, y, cfg.num_clients, seed=0)
 
@@ -348,7 +346,7 @@ def _measure_compression(rounds, quiet, kinds=("none", "int8", "bf16", "topk")):
                 else CompressionConfig(kind=kind, topk_frac=0.05))
         cfg = FedConfig(algo="fedcm", num_clients=64, cohort_size=cohort,
                         local_steps=K, participation="fixed",
-                        use_fused_kernel=True, compression=comp)
+                        compression=comp)
         eng = FederatedEngine(cfg, loss_fn, batch_size=B)
         data = FederatedData(x, y, cfg.num_clients, seed=0)
 
@@ -414,7 +412,7 @@ def _measure_store_prefetch(rounds, alts, quiet, n_clients=256, cohort=16):
     for key, pf in (("sync", False), ("prefetch", True)):
         cfg = FedConfig(algo="scaffold", num_clients=n_clients,
                         cohort_size=cohort, local_steps=K,
-                        participation="fixed", use_fused_kernel=True,
+                        participation="fixed",
                         population_store="host", store_prefetch=pf)
         engines[key] = FederatedEngine(cfg, loss_fn, batch_size=B)
     data = FederatedData(x, y, n_clients, seed=0)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.fed_direction.kernel import fed_direction_flat
-from repro.kernels.fedcm_update.ref import fedcm_step_ref
+from repro.kernels.fed_direction.ref import fedcm_step_ref
 
 
 def fedcm_update_accounting(n_params: int) -> dict:
@@ -42,9 +42,8 @@ def main() -> int:
               f"fused={acc['fused_bytes']/1e9:7.2f} GB  saving={acc['saving']:.0%}")
 
     print("\n### correctness at size (interpret mode)")
-    # the FedCM blend now launches through the generalized fed_direction
-    # kernel (the dedicated fedcm_update kernel is retired to ref-only);
-    # coefficients (η, α, 0, 1−α) select the blend form
+    # the FedCM blend launches through the generalized fed_direction
+    # kernel; coefficients (η, α, 0, 1−α) select the blend form
     rng = np.random.default_rng(0)
     n = 4_000_000
     x = jnp.asarray(rng.normal(size=n), jnp.float32)

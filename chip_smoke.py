@@ -7,14 +7,17 @@ user calls.  Weights and data are random, made from ``--seed``.
 Phase A, ``fed_train``'s main path at the paper's Setting I
 (``configs/fedcm_paper.py``): 100 clients, 10% Bernoulli participation,
 fedcm (α = 0.1, η_l = 0.1), Dirichlet(0.6), K = 50 local steps, 20 rounds as
-two fused chunks of 10.  ``run_federated`` runs it with the Pallas kernels
-and with plain jnp, on the f32 wire and on the int8 wire (the dequant fold).
+two fused chunks of 10.  On the f32 wire ``run_federated`` runs the flat
+engine (the Pallas kernels) against the tree oracle (``use_flat_plane=False``,
+plain jnp).  The int8 wire (the dequant fold) exists on the flat engine
+only, so there the compiled kernels (Mosaic) run against the same run with
+``repro.kernels.interpret_mode`` patched to the Pallas interpreter.
 
 Phase B, a real-width LM client through ``FederatedEngine.run_rounds``:
 llama3.2-1b at its published widths (d_model 2048, 32 Q / 8 KV heads of 64,
 gated-SiLU d_ff 8192), depth cut to 2 layers and the vocabulary to an
 eighth (16,032); fedcm, 16 clients, cohort 2, K = 2, B = 4, S = 512,
-3 rounds, kernel path against jnp path.
+3 rounds, the flat engine against the tree oracle.
 
 ``--chips 4`` runs only the sharded path and its reference: Phase A with
 ``--cohort-shard 4`` against the same run unsharded on one chip (server
@@ -40,6 +43,7 @@ import tempfile
 import time
 from pathlib import Path
 from types import SimpleNamespace
+from unittest import mock
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
@@ -48,6 +52,7 @@ import numpy as np
 import jax
 
 from repro.configs.base import CompressionConfig, FedConfig, get_config
+from repro import kernels
 from repro.configs.fedcm_paper import DIRICHLET_ALPHA, SETTING_I
 from repro.core import FederatedEngine
 from repro.data import FederatedTokens, make_federated_lm_corpus
@@ -56,8 +61,9 @@ from repro.launch.fed_train import run_federated
 from repro.models import build_model, federated_lm_loss
 from repro.utils.compile_cache import use_compile_cache
 
-# Kernel path against jnp path.  The two differ only in how the f32 update
-# arithmetic is rounded and contracted.
+# The flat engine against the tree oracle, or Mosaic against the Pallas
+# interpreter.  The two differ only in how the f32 update arithmetic is
+# rounded and contracted.
 RTOL_F32_CLIENT = 1e-3  # Phase A: Setting I's losses are ~1e-5, so one
 #                         rounding step in the update moves them ~5e-4
 RTOL_LM_CLIENT = 1e-5  # Phase B: the chip measured 0.0
@@ -186,34 +192,40 @@ def compare(label, got, want, rtol, failures):
 
 
 def phase_a(failures, *, base=PHASE_A, chunk=PHASE_A_CHUNK, seed=0, **data_kw):
-    """Setting I through ``run_federated``, kernel path against jnp path,
-    on the f32 wire and the int8 wire."""
+    """Setting I through ``run_federated``: on the f32 wire the flat engine
+    against the tree oracle; on the int8 wire, which only the flat engine
+    speaks, Mosaic against the Pallas interpreter."""
     base = dataclasses.replace(base, seed=seed)
-    for wire in ("f32", "int8"):
-        comp = None if wire == "f32" else CompressionConfig(kind=wire, seed=seed)
-        losses = {}
-        for kernel in (True, False):
-            cfg = dataclasses.replace(base, use_fused_kernel=kernel, compression=comp)
-            label = f"A[{wire},{'kernel' if kernel else 'jnp'}]"
-            readings, acc, losses[kernel] = observed_federated_run(
-                cfg, chunk=chunk, seed=seed, **data_kw)
-            say(label, **readings, chunk_losses=losses[kernel], test_acc=acc)
-            if kernel and not readings["tpu_custom_call"]:
-                failures.append(f"{label}: no Pallas kernel in the compiled round")
-        compare(f"A[{wire}] kernel vs jnp", losses[True], losses[False],
-                RTOL_F32_CLIENT, failures)
+
+    def run(label, cfg, compiled):
+        readings, acc, losses = observed_federated_run(
+            cfg, chunk=chunk, seed=seed, **data_kw)
+        say(label, **readings, chunk_losses=losses, test_acc=acc)
+        if compiled and not readings["tpu_custom_call"]:
+            failures.append(f"{label}: no Pallas kernel in the compiled round")
+        return losses
+
+    kernel = run("A[f32,kernel]", base, True)
+    tree = run("A[f32,tree]", dataclasses.replace(base, use_flat_plane=False), False)
+    compare("A[f32] kernel vs tree", kernel, tree, RTOL_F32_CLIENT, failures)
+    int8 = dataclasses.replace(base, compression=CompressionConfig(kind="int8", seed=seed))
+    mosaic = run("A[int8,mosaic]", int8, True)
+    with mock.patch.object(kernels, "interpret_mode", lambda: True):
+        interpreted = run("A[int8,interpreter]", int8, False)
+    compare("A[int8] Mosaic vs interpreter", mosaic, interpreted, RTOL_F32_CLIENT,
+            failures)
 
 
 def phase_a_sharded(failures, *, shards=4, base=PHASE_A, chunk=PHASE_A_CHUNK,
                     seed=0, **data_kw):
-    """Setting I on the kernel path with the cohort sharded over ``shards``
+    """Setting I on the flat engine with the cohort sharded over ``shards``
     chips, against the same run unsharded on one chip.  The run state
     (``fed_train``'s last snapshot) is held to f32-bitwise.  The loss
     metric is held to ``RTOL_SHARDED_LOSS``: each client's loss is reduced
     at a different vmap width per device, and XLA may associate that
     reduce differently (1-ulp differences on 8 CPU devices, with the
     state bitwise)."""
-    base = dataclasses.replace(base, seed=seed, use_fused_kernel=True)
+    base = dataclasses.replace(base, seed=seed)
     out = {}
     for n in (shards, 0):
         cfg = dataclasses.replace(base, cohort_shard=n)
@@ -241,12 +253,12 @@ def phase_a_sharded(failures, *, shards=4, base=PHASE_A, chunk=PHASE_A_CHUNK,
             failures)
 
 
-def lm_engine(mcfg, *, cohort, kernel, shards=0, rounds, seed):
+def lm_engine(mcfg, *, cohort, flat=True, shards=0, rounds, seed):
     model = build_model(mcfg)
     cfg = FedConfig(algo="fedcm", num_clients=LM_CLIENTS, cohort_size=cohort,
                     participation="fixed", local_steps=LM_K, alpha=0.1,
                     eta_l=0.1, rounds=rounds, seed=seed,
-                    use_fused_kernel=kernel, cohort_shard=shards)
+                    use_flat_plane=flat, cohort_shard=shards)
     eng = FederatedEngine(cfg, federated_lm_loss(model), batch_size=LM_BATCH)
     return eng, model
 
@@ -257,13 +269,13 @@ def lm_data(mcfg, seed, seq=LM_SEQ):
 
 
 def phase_b(failures, *, mcfg=None, cohort=2, rounds=3, seed=0, seq=LM_SEQ):
-    """The real-width LM client, kernel path against jnp path."""
+    """The real-width LM client, the flat engine against the tree oracle."""
     mcfg = mcfg or lm_client_config()
     data = lm_data(mcfg, seed, seq)
     losses = {}
     for kernel in (True, False):
-        label = f"B[{'kernel' if kernel else 'jnp'}]"
-        eng, model = lm_engine(mcfg, cohort=cohort, kernel=kernel,
+        label = f"B[{'kernel' if kernel else 'tree'}]"
+        eng, model = lm_engine(mcfg, cohort=cohort, flat=kernel,
                                rounds=rounds, seed=seed)
         state = eng.init(model.init(jax.random.PRNGKey(seed)),
                          jax.random.PRNGKey(seed + 1))
@@ -280,7 +292,7 @@ def phase_b(failures, *, mcfg=None, cohort=2, rounds=3, seed=0, seq=LM_SEQ):
         elif not losses[kernel][-1] < losses[kernel][0]:
             failures.append(f"{label}: loss did not fall "
                             f"({losses[kernel][0]:g} -> {losses[kernel][-1]:g})")
-    compare("B kernel vs jnp", losses[True], losses[False], RTOL_LM_CLIENT,
+    compare("B kernel vs tree", losses[True], losses[False], RTOL_LM_CLIENT,
             failures)
 
 
@@ -290,7 +302,7 @@ def phase_b_sharded(failures, *, shards=4, mcfg=None, rounds=2, seed=0,
     unsharded round at that cohort does not fit one chip, so there is no
     reference: the losses must be finite."""
     mcfg = mcfg or lm_client_config()
-    eng, model = lm_engine(mcfg, cohort=shards, kernel=True, shards=shards,
+    eng, model = lm_engine(mcfg, cohort=shards, shards=shards,
                            rounds=rounds, seed=seed)
     state = eng.init(model.init(jax.random.PRNGKey(seed)),
                      jax.random.PRNGKey(seed + 1))

@@ -1,7 +1,7 @@
 """Layer 2 — traced-program contract checker for the round engine.
 
 Where :mod:`repro.analysis.lint` reads source, this module lowers the
-*actual* round programs (``run_rounds`` sync/async × jnp/kernel ×
+*actual* round programs (``run_rounds`` sync/async ×
 resident/store × cohort-sharded) on tiny synthetic problems and asserts
 the contracts that only exist after tracing:
 
@@ -245,23 +245,18 @@ class MatrixEntry:
 
 
 MATRIX: Sequence[MatrixEntry] = (
-    MatrixEntry("sync/jnp/resident", "sync", {}),
-    MatrixEntry("sync/kernel/resident", "sync", {"use_fused_kernel": True}),
-    MatrixEntry("async/jnp/resident", "async", {}),
-    MatrixEntry("async/kernel/resident", "async", {"use_fused_kernel": True}),
-    MatrixEntry("sync/kernel/sharded", "sync",
-                {"use_fused_kernel": True, "cohort_shard": 1}, sharded=True),
-    MatrixEntry("sync/kernel/bf16", "sync", {"use_fused_kernel": True},
-                bf16=True),
-    MatrixEntry("sync/kernel/store", "sync",
-                {"use_fused_kernel": True, "population_store": "host"},
+    MatrixEntry("sync/resident", "sync", {}),
+    MatrixEntry("async/resident", "async", {}),
+    MatrixEntry("sync/sharded", "sync", {"cohort_shard": 1}, sharded=True),
+    MatrixEntry("sync/bf16", "sync", {}, bf16=True),
+    MatrixEntry("sync/store", "sync", {"population_store": "host"},
                 algo="scaffold", store=True),
-    MatrixEntry("async/jnp/store", "async", {"population_store": "host"},
+    MatrixEntry("async/store", "async", {"population_store": "host"},
                 algo="scaffold", store=True),
 )
 
 # the fast subset CI's static-analysis job runs inside the tier-1 budget
-QUICK = ("sync/kernel/resident", "async/kernel/resident")
+QUICK = ("sync/resident", "async/resident")
 
 
 def _check_resident(entry: MatrixEntry) -> ContractReport:
@@ -367,15 +362,12 @@ def run_matrix(quick: bool = False,
     return [(_check_store if e.store else _check_resident)(e) for e in todo]
 
 
-def quick_contracts(*, use_async: bool = False,
-                    use_fused_kernel: bool = True) -> Dict[str, object]:
+def quick_contracts(*, use_async: bool = False) -> Dict[str, object]:
     """One-path contract summary for the ``fed_train --dryrun`` artifact.
 
     Memoized per path: dry-runs in one process (the CLI test suite) pay
     the tiny compile once."""
-    mode = "async" if use_async else "sync"
-    kern = "kernel" if use_fused_kernel else "jnp"
-    name = f"{mode}/{kern}/resident"
+    name = f"{'async' if use_async else 'sync'}/resident"
     if name not in _QUICK_CACHE:
         entry = next(e for e in MATRIX if e.name == name)
         rep = _check_resident(entry)

@@ -340,14 +340,12 @@ class FedConfig:
     # for tensor-sharded lowering (launch/fed_dryrun pins it off: a flat
     # concat of model-sharded leaves would force all-gathers).
     use_flat_plane: bool = True
-    # route the per-local-step update x ← x − η_l·v through the fused
-    # Pallas kernels instead of unfused jnp arithmetic — flat plane only:
-    # kernels/fed_direction (all algorithms) plus the fused
-    # kernels/server_update round-close (fedavg/fedcm/scaffold/mimelite).
-    # The legacy whole-tree kernels/fedcm_update launch is retired; on the
-    # tree path this flag is inert.  ref.py files are the oracles
-    # (tests/test_run_rounds.py, tests/test_kernels.py).
-    use_fused_kernel: bool = False
+    # the flat engine has one path: the fused Pallas kernels
+    # (kernels/fed_direction, kernels/server_update), run by Mosaic on a
+    # TPU and by the Pallas interpreter elsewhere.  True is the only
+    # accepted value (FederatedEngine refuses False); the field goes when
+    # the benchmark's traffic files stop naming it.
+    use_fused_kernel: bool = True
     # async pipelined engine (engine.run_rounds_async): number of cohorts
     # in flight.  1 = the sync schedule (each cohort folds the round it
     # launches); D > 1 overlaps D cohorts — a fold is D−1 rounds stale.
@@ -363,7 +361,7 @@ class FedConfig:
     # axis over (engine builds a ("clients",) mesh over the first N
     # visible devices and runs the cohort via shard_map; the fold lowers
     # to a reduce-scatter/all-gather).  0 = single-device execution.
-    # Requires use_flat_plane + use_fused_kernel.  An explicit mesh can
+    # Requires use_flat_plane.  An explicit mesh can
     # instead be passed as FederatedEngine(..., cohort_mesh=...).
     cohort_shard: int = 0
     # ---- population store / streaming availability (million-client axis) --

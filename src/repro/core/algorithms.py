@@ -368,10 +368,10 @@ def sparse_client_finalize(
 ) -> FlatClientOutputs:
     """``algo.client_finalize`` minus the zeros trees it materializes:
     unused planes come back ``None``.  Array-polymorphic — the flat
-    engine's kernel path feeds it bare ``(P,)`` buffers (single-leaf
-    pytrees), the jnp path feeds leaf trees.  Op order deliberately
-    mirrors the tree finalizer exactly (same ``state_update_fn``), so flat
-    and tree trajectories agree bitwise, not just to tolerance."""
+    engine feeds it bare ``(P,)`` buffers (single-leaf pytrees).  Op
+    order deliberately mirrors the tree finalizer exactly (same
+    ``state_update_fn``), so flat and tree trajectories agree bitwise, not
+    just to tolerance."""
     delta = tree_sub(xK, x0)
     state_delta = None
     if algo.needs_client_state and algo.state_update_fn is not None:

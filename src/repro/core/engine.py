@@ -74,21 +74,16 @@ paths.
 Flat parameter plane (``cfg.use_flat_plane``, default on): params and
 server momentum/second-moment are ravelled ONCE per ``run_rounds`` call
 (``repro.core.flat.FlatSpec``) into contiguous ``(P,)`` buffers that carry
-the round-scope state; every round-scope reduction lands flat — masked
-cohort means concatenate per-leaf contractions into ONE ``(P,)`` buffer,
-the server update and metric norms are single fused ops, and stateless
-algorithms never materialize the zero state/extra planes the tree path
-builds and aggregates.  The K-step local scan itself keeps the LEAF form
-(model autodiff is per-leaf; a flat↔tree conversion per step would
-concatenate and split the whole plane), so its body is bitwise the tree
-path's.  Under ``use_fused_kernel`` the scan flips to the flat ``(P,)``
-carry — the kernels consume flat buffers directly, per-client control
-variates ride an ``(N, P)`` plane (ONE gather/scatter), and a tree-form
-kernel's per-step concatenate/split is gone.  There the plane carries a
-zero tail up to the kernels' block length (``_flat_spec``), so no launch
-pads or slices a plane.  The tree path
-(``use_flat_plane=False``) is retained verbatim as the numerical oracle
-(tests/test_flat.py) and for tensor-sharded lowering (launch/fed_dryrun).
+the round-scope state, and per-client control variates into one ``(N, P)``
+plane (ONE gather and ONE scatter a round).  The K-step local scan carries
+the ``(P,)`` plane: the loss reads its leaves through ``FlatSpec.unravel``
+and the gradient returns to the plane as the view's backward, one
+concatenate of the leaf gradients.  The client outputs ARE ``(C, P)``
+planes, the shape the fold kernel reads.  The plane carries a zero tail up
+to the kernels' block length (``_flat_spec``), so no launch pads or slices
+a plane.  The tree path (``use_flat_plane=False``) is retained verbatim as
+the numerical oracle (tests/test_flat.py) and for tensor-sharded lowering
+(launch/fed_dryrun).
 
 The algorithm layer is the declarative registry (``repro.core.registry``):
 the engine consumes ONE ``AlgorithmSpec`` per run — its direction
@@ -98,15 +93,14 @@ drive ``FedState`` allocation and payload accounting.  The engine contains
 zero per-algorithm branches; registering a new spec makes it runnable on
 every path below.
 
-``cfg.use_fused_kernel`` routes the update phase through Pallas — flat
-plane only: the per-local-step direction via ``kernels/fed_direction``
-(the spec's ``DirectionRow`` becomes the SMEM coefficient vector) and the
-round-close masked-mean + momentum EMA + param step via
-``kernels/server_update`` (one launch per ``FoldPass``; specs with a
-``server_fn`` escape hatch fall back to the jnp reduction).  The legacy
-whole-tree ``fedcm_update`` launch is retired from the tree path (its
-``ref.py`` stays as a blend oracle); on the tree path the flag is inert.
-Each kernel's ``ref.py`` is its oracle.
+The flat engine's update phase runs through Pallas: the per-local-step
+direction via ``kernels/fed_direction`` (the spec's ``DirectionRow``
+becomes the SMEM coefficient vector) and the round-close masked-mean +
+momentum EMA + param step via ``kernels/server_update`` (one launch per
+``FoldPass``; specs with a ``server_fn`` escape hatch take a jnp
+reduction of the ``(C, P)`` planes instead).  The kernels choose Mosaic or
+the Pallas interpreter from the platform (``repro.kernels.interpret_mode``);
+nothing else chooses.  Each kernel's ``ref.py`` is its oracle.
 
 Async pipelined engine (``run_rounds_async``): overlapping cohorts as ONE
 ``lax.scan`` whose carry adds a static depth-D ring of in-flight cohort
@@ -135,8 +129,8 @@ what keeps sharded execution f32-BITWISE against the unsharded engine —
 for every registered algorithm, sync and async
 (tests/test_cohort_shard.py).  Under ``run_rounds_async`` the ring
 carries client-sharded planes, so the fold's collective sits D−1 rounds
-behind the launch it consumes — the latency the overlap hides.  Flat +
-kernel path only; the spec's ``server_post_fn`` runs replicated after
+behind the launch it consumes — the latency the overlap hides.  Flat
+plane only; the spec's ``server_post_fn`` runs replicated after
 the gather, and ``server_fn`` escape hatches get scattered means
 (``repro.core.flat.cohort_mean_scatter``) into a replicated escape.
 
@@ -246,7 +240,6 @@ from repro.sharding.rules import (
 from repro.utils.compat import shard_map
 from repro.utils.compile_cache import key_on_metadata
 from repro.utils.trees import (
-    ravel_leaves,
     tree_axpy,
     tree_bytes,
     tree_zeros_like,
@@ -278,7 +271,7 @@ class FlatMaster(NamedTuple):
 
     params: jax.Array  # (P,) f32
     second_moment: Optional[jax.Array]  # (P,) f32, or None (spec doesn't need v)
-    client_states: Optional[jax.Array]  # (N, P) f32 (kernel path) or None
+    client_states: Optional[jax.Array]  # (N, P) f32, or None (stateless spec)
 
 
 class FedState(NamedTuple):
@@ -514,80 +507,43 @@ def flat_client_update(
     loss_fn: Callable[[Any, Any], jax.Array],
     spec: FlatSpec,
     x_t: jax.Array,  # (P,) broadcast round anchor (flat)
-    x0_tree,  # the same anchor as a tree (unravelled ONCE per round)
     m_t: jax.Array,  # (P,) Δ_t (or c for scaffold; zeros otherwise)
-    m_tree,  # its tree view (unravelled ONCE per round)
-    cst_tree_i,  # this client's c_i / λ_i as a tree slice, or None
-    cst_flat_i,  # the same as a (P,) plane row, or None
+    cst_i,  # this client's c_i / λ_i as a (P,) plane row, or None
     batches,  # pytree of (K, B, …) local minibatches
     eta_l,
     full_grad_batch=None,  # MimeLite: the client's whole dataset
     unroll: bool = False,  # dry-run analysis: count every local step
 ):
-    """One client's K local steps, finalized onto flat-engine outputs.
+    """One client's K local steps on the flat ``(P,)`` carry, finalized
+    onto flat-engine outputs.
 
-    jnp path: the local scan carries the LEAF form — model autodiff is
-    per-leaf anyway, and a flat↔tree conversion per step would put a
-    concatenate and a split of the whole plane into every local step — so
-    the step body is bitwise the tree path's, and the client's outputs stay
-    leaf trees with ``None`` for unused planes
-    (``sparse_client_finalize``).  The engine then reduces them straight to
-    flat ``(P,)`` MEANS — the full ``(C, P)`` cohort plane is never
-    materialized (a batched concatenate costs more than the per-leaf
-    contractions it would save).
-
-    ``cfg.use_fused_kernel`` flips the scan onto the flat ``(P,)`` carry
-    instead: the ``fed_direction`` kernel consumes flat buffers directly
-    (the loss unravels the plane by slicing, which fuses on TPU where
-    this path is aimed, and its gradient returns to the plane as the
-    view's backward, one concatenate of the leaf gradients) and the outputs
-    ARE ``(P,)`` planes, giving the ``(C, P)`` delta plane the fused
-    ``server_update`` kernel wants for free.
+    The ``fed_direction`` kernel consumes flat buffers directly; the loss
+    unravels the plane by slicing, which fuses on TPU, and its gradient
+    returns to the plane as the view's backward, one concatenate of the
+    leaf gradients.  The outputs ARE ``(P,)`` planes, giving the
+    ``(C, P)`` delta plane the fused ``server_update`` kernel wants for
+    free; unused planes are ``None`` (``sparse_client_finalize``).
     """
-    if cfg.use_fused_kernel:
-        def flat_loss(flat, batch):
-            return loss_fn(spec.unravel(flat), batch)
-
-        def step(x, batch):
-            with jax.named_scope(LOCAL_STEPS_SCOPE):
-                loss, g = jax.value_and_grad(flat_loss)(x, batch)
-                if cfg.weight_decay:
-                    g = cfg.weight_decay * x + g
-            x = flat_direction_step(algo, cfg, x, g, m_t, cst_flat_i, x_t, eta_l)
-            return x, loss
-
-        xK_flat, losses = jax.lax.scan(step, x_t, batches,
-                                       unroll=cfg.local_steps if unroll else 1)
-        with jax.named_scope(LOCAL_STEPS_SCOPE):
-            full_grad = None
-            if algo.needs_full_grad:
-                assert full_grad_batch is not None
-                full_grad = jax.grad(flat_loss)(x_t, full_grad_batch)
-            outs = sparse_client_finalize(algo, cfg, x_t, xK_flat, cst_flat_i,
-                                          m_t, eta_l, full_grad)
-        return outs, jnp.mean(losses)
+    def flat_loss(flat, batch):
+        return loss_fn(spec.unravel(flat), batch)
 
     def step(x, batch):
         with jax.named_scope(LOCAL_STEPS_SCOPE):
-            loss, g = jax.value_and_grad(loss_fn)(x, batch)
+            loss, g = jax.value_and_grad(flat_loss)(x, batch)
             if cfg.weight_decay:
-                g = tree_axpy(cfg.weight_decay, x, g)
-        v = algo.direction(cfg, m_tree, cst_tree_i, x, x0_tree, g)
-        # keep the carry dtype stable (bf16 params + f32 momentum promote)
-        x = jax.tree_util.tree_map(
-            lambda xi, vi: (xi - eta_l * vi).astype(xi.dtype), x, v
-        )
+                g = cfg.weight_decay * x + g
+        x = flat_direction_step(algo, cfg, x, g, m_t, cst_i, x_t, eta_l)
         return x, loss
 
-    xK, losses = jax.lax.scan(step, x0_tree, batches,
+    xK, losses = jax.lax.scan(step, x_t, batches,
                               unroll=cfg.local_steps if unroll else 1)
     with jax.named_scope(LOCAL_STEPS_SCOPE):
         full_grad = None
         if algo.needs_full_grad:
             assert full_grad_batch is not None
-            full_grad = jax.grad(loss_fn)(x0_tree, full_grad_batch)
-        outs = sparse_client_finalize(algo, cfg, x0_tree, xK, cst_tree_i,
-                                      m_tree, eta_l, full_grad)
+            full_grad = jax.grad(flat_loss)(x_t, full_grad_batch)
+        outs = sparse_client_finalize(algo, cfg, x_t, xK, cst_i,
+                                      m_t, eta_l, full_grad)
     return outs, jnp.mean(losses)
 
 
@@ -623,6 +579,12 @@ class FederatedEngine:
         client_sharding: Optional[Any] = None,  # NamedSharding for the cohort axis
         cohort_mesh: Optional[Any] = None,  # Mesh with a "clients" axis
     ) -> None:
+        if not cfg.use_fused_kernel:
+            raise ValueError(
+                "the flat engine has one path, the Pallas kernels (the "
+                "platform picks Mosaic or the interpreter): False is no "
+                "longer accepted"
+            )
         self.cfg = cfg
         self.algo = get_algorithm(cfg.algo)
         self.loss_fn = loss_fn
@@ -693,13 +655,6 @@ class FederatedEngine:
                     "plane — it shards (C, P) uplink planes; set "
                     "cfg.use_flat_plane=True (the tree path stays the "
                     "single-device oracle)"
-                )
-            if not cfg.use_fused_kernel:
-                raise ValueError(
-                    "cohort-parallel execution rides the flat+kernel path "
-                    "(clients produce (C, P) planes, the fold is the "
-                    "scattered server kernel) — set cfg.use_fused_kernel="
-                    "True / pass --fused-kernel"
                 )
             if client_sharding is not None:
                 raise ValueError(
@@ -788,7 +743,7 @@ class FederatedEngine:
                 return state
             if self._needs_master(spec):
                 cst = None
-                if state.client_states is not None and self.cfg.use_fused_kernel:
+                if state.client_states is not None:
                     cst = spec.ravel(state.client_states, batch_dims=1)
                 sm = state.server.second_moment
                 state = state._replace(master=FlatMaster(
@@ -814,14 +769,11 @@ class FederatedEngine:
         return self.compression is not None and self.compression.kind == "topk"
 
     def _flat_spec(self, params) -> FlatSpec:
-        """The flat plane of ``params`` as this engine lays it out.  On the
-        kernel path its length is a multiple of the launches' block
-        (``plane_alignment`` of the size and the cohort shards), so the
-        kernels neither pad their operands nor slice their outputs; the
-        jnp path's plane is the leaves alone."""
+        """The flat plane of ``params`` as this engine lays it out: its
+        length is a multiple of the launches' block (``plane_alignment`` of
+        the size and the cohort shards), so the kernels neither pad their
+        operands nor slice their outputs."""
         spec = FlatSpec.from_tree(params)
-        if not self.cfg.use_fused_kernel:
-            return spec
         return spec.aligned(plane_alignment(spec.size, self._cohort_shards))
 
     # -------------------------------------------------- payload accounting
@@ -875,11 +827,8 @@ class FederatedEngine:
         """Tree state → flat-plane state: the ONE ravel of a run_rounds call.
         Params/second-moment become f32 ``(P,)`` planes and momentum a
         ``momentum_dtype`` plane.  Stacked per-client control variates
-        become an ``(N, P)`` plane on the kernel path (whose clients
-        produce flat buffers anyway, so gather/scatter are ONE op each);
-        the jnp path keeps them in leaf form — its local steps consume
-        leaves, and a per-round (C, P) concatenate costs more than the
-        per-leaf gather/scatter it would replace.
+        become an ``(N, P)`` plane (the clients produce flat buffers, so
+        gather/scatter are ONE op each).
 
         A carried ``state.master`` (sub-f32 trees) takes precedence over
         re-ravelling the rounded leaves: that is what makes sequential
@@ -896,7 +845,7 @@ class FederatedEngine:
             round=state.server.round,
         )
         fcst = state.client_states
-        if fcst is not None and cfg.use_fused_kernel:
+        if fcst is not None:
             fcst = (mst.client_states if mst is not None and
                     mst.client_states is not None
                     else spec.ravel(fcst, batch_dims=1))
@@ -915,15 +864,14 @@ class FederatedEngine:
             round=fstate.server.round,
         )
         cst = fstate.client_states
-        cst_is_plane = cst is not None and cfg.use_fused_kernel
-        if cst_is_plane:
+        if cst is not None:
             cst = spec.unravel(cst)
         master = None
         if self._needs_master(spec):
             master = FlatMaster(
                 params=fstate.params,
                 second_moment=fstate.server.second_moment,
-                client_states=fstate.client_states if cst_is_plane else None,
+                client_states=fstate.client_states,
             )
         return FedState(spec.unravel(fstate.params), srv, cst, fstate.rng,
                         master, residuals=fstate.residuals)
@@ -942,50 +890,29 @@ class FederatedEngine:
         f32 block from the population store, replacing the resident-plane
         gather; the per-client math downstream is identical either way.
 
-        Returns (outs, losses, cohort_cst, cohort_cst_tree): cohort_cst is
-        the (C, P) gathered client-state plane on the kernel path,
-        cohort_cst_tree its leaf-form counterpart on the jnp path (None
-        where unused)."""
+        Returns (outs, losses, cohort_cst): cohort_cst is the (C, P)
+        gathered client-state plane (None for stateless specs)."""
         cfg, algo = self.cfg, self.algo
         batches = self._constrain_cohort(batches)
-
         x_t = fstate.params  # (P,) f32
-        # leaf views for the local scan — unravelled ONCE per round (x0 is
-        # the scan carry init, so its slices materialize at loop entry; the
-        # momentum view is a loop-invariant closure)
-        x0_tree = spec.unravel(x_t)
-        m_tree = spec.unravel(m_t, dtype=cfg.momentum_dtype)
 
-        cohort_cst = cohort_cst_tree = None
+        cohort_cst = None
         if algo.needs_client_state:
-            if cohort_rows is not None:  # store-backed: rows came from host
-                if cfg.use_fused_kernel:
-                    cohort_cst = self._constrain_cohort(cohort_rows)
-                else:  # leaf form, as the local steps consume it — the
-                    # unravel restores leaf dtypes, matching the resident
-                    # per-leaf gather bitwise (rows are exact f32 ravels)
-                    cohort_cst_tree = self._constrain_cohort(
-                        spec.unravel(cohort_rows)
-                    )
-            elif cfg.use_fused_kernel:  # (N, P) plane: ONE gather
-                cohort_cst = self._constrain_cohort(fstate.client_states[ids])
-            else:  # leaf form, as the local steps consume it
-                cohort_cst_tree = self._constrain_cohort(
-                    jax.tree_util.tree_map(lambda a: a[ids], fstate.client_states)
-                )
+            if cohort_rows is None:  # (N, P) plane: ONE gather
+                cohort_rows = fstate.client_states[ids]
+            cohort_cst = self._constrain_cohort(cohort_rows)
         full = None
         if algo.needs_full_grad:
             full = self._constrain_cohort(full_batches)
 
-        def one_client(cst_tree_i, cst_flat_i, batches_i, full_i):
+        def one_client(cst_i, batches_i, full_i):
             return flat_client_update(
-                algo, cfg, self.loss_fn, spec, x_t, x0_tree, m_t, m_tree,
-                cst_tree_i, cst_flat_i, batches_i, eta_l,
-                full_grad_batch=full_i, unroll=self.analysis_unroll,
+                algo, cfg, self.loss_fn, spec, x_t, m_t, cst_i, batches_i,
+                eta_l, full_grad_batch=full_i, unroll=self.analysis_unroll,
             )
 
-        outs, losses = jax.vmap(one_client)(cohort_cst_tree, cohort_cst, batches, full)
-        return outs, losses, cohort_cst, cohort_cst_tree
+        outs, losses = jax.vmap(one_client)(cohort_cst, batches, full)
+        return outs, losses, cohort_cst
 
     # -------------------------------------------------- cohort-parallel
     @property
@@ -1012,7 +939,7 @@ class FederatedEngine:
         before entry (replicated rng), ``fed_direction`` kernel launches
         stay device-local, and no collective runs until the fold.
 
-        Same contract as ``_flat_cohort_pass`` (kernel-path layout), with
+        Same contract as ``_flat_cohort_pass``, with
         the cohort axis PADDED to the shard count: ``outs`` planes are
         ``(C_pad, P)`` sharded over clients, ``losses`` is ``(C_pad,)``,
         and ``cohort_cst`` is the UNpadded ``(C, P)`` gather (the
@@ -1031,14 +958,10 @@ class FederatedEngine:
         plane_keys = tuple(algo.uplink_planes)
 
         def shard_body(x_t, m_t, eta_l, operands):
-            x0_tree = spec.unravel(x_t)
-            m_tree = spec.unravel(m_t, dtype=cfg.momentum_dtype)
-
             def one_client(cst_i, batches_i, full_i):
                 return flat_client_update(
-                    algo, cfg, self.loss_fn, spec, x_t, x0_tree, m_t, m_tree,
-                    None, cst_i, batches_i, eta_l,
-                    full_grad_batch=full_i, unroll=self.analysis_unroll,
+                    algo, cfg, self.loss_fn, spec, x_t, m_t, cst_i, batches_i,
+                    eta_l, full_grad_batch=full_i, unroll=self.analysis_unroll,
                 )
 
             outs, losses = jax.vmap(one_client)(
@@ -1071,7 +994,7 @@ class FederatedEngine:
         losses = jax.lax.with_sharding_constraint(
             out["losses"], NamedSharding(self.cohort_mesh, P())
         )
-        return outs, losses, cohort_cst, None
+        return outs, losses, cohort_cst
 
     def _sharded_round_close(self, algo, fsrv, outs, wp, n_active, x_t, eta_l,
                              discount=1.0):
@@ -1128,7 +1051,7 @@ class FederatedEngine:
     def _sharded_means(self, outs, wp, n_active):
         """Masked cohort means of every uplink plane as scattered
         reductions (``cohort_mean_scatter`` inside ``shard_map``) — the
-        sharded analog of the kernel-path ``_masked_pmean`` calls feeding
+        sharded analog of the ``_masked_pmean`` calls feeding
         a ``server_fn`` escape-hatch spec.  Returns (mean_delta, mean_sd,
         mean_extra) with ``None`` for planes the spec never produced."""
         cfg = self.cfg
@@ -1173,7 +1096,7 @@ class FederatedEngine:
         0-weight NaN row still poisons tensordot/scatter reductions.  When
         ``cfg.fault`` is None nothing here is traced: fault-free programs
         are bitwise the pre-fault engine's.  Representation-generic over
-        the kernel (C[, pad], P) planes and the jnp/tree (C, leaf…) trees;
+        the flat (C[, pad], P) planes and the tree path's (C, leaf…) trees;
         under cohort sharding the plane ops run on padded rows (pad rows
         carry mask=False and are never corrupted or counted)."""
         fault = getattr(self.cfg, "fault", None)
@@ -1181,11 +1104,9 @@ class FederatedEngine:
         if fault is None:
             return mask, outs, zero, zero
         C = mask.shape[0]
-        # kernel-path planes under cohort sharding carry C_pad rows
-        padded = self._sharded and self.cfg.use_fused_kernel
 
-        def pad_mask(v):
-            return self._pad_cohort(v, mode="zero") if padded else v
+        def pad_mask(v):  # planes under cohort sharding carry C_pad rows
+            return self._pad_cohort(v, mode="zero") if self._sharded else v
 
         plan = fault_masks(fault, t, ids)
         n_dropped = zero
@@ -1195,14 +1116,14 @@ class FederatedEngine:
         if fault.corrupt_rate > 0.0:
             cmask = pad_mask(plan.corrupt & mask)
             nkeys = plan.noise_keys
-            if nkeys is not None and padded:
+            if nkeys is not None and self._sharded:
                 nkeys = self._pad_cohort(nkeys)  # edge pad; cmask=False there
             outs = outs._replace(
                 delta=corrupt_uplink(fault, cmask, nkeys, outs.delta))
         n_quar = zero
         if fault.quarantine:
             rows = (padded_cohort(cohort_capacity(self.cfg),
-                                  self._cohort_shards) if padded else C)
+                                  self._cohort_shards) if self._sharded else C)
             fin = (rows_finite(outs.delta, rows)
                    & rows_finite(outs.state_delta, rows)
                    & rows_finite(outs.extra, rows))
@@ -1220,7 +1141,7 @@ class FederatedEngine:
                 state_delta=zero_rows(outs.state_delta, bad),
                 extra=zero_rows(outs.extra, bad),
             )
-            mask = mask & ~(bad[:C] if padded else bad)
+            mask = mask & ~bad[:C]
         return mask, outs, n_dropped, n_quar
 
     def _store_io(self, fn, *args):
@@ -1245,30 +1166,19 @@ class FederatedEngine:
                 attempt += 1
 
     def _masked_pmean(self, x, w, n_active):
-        """Masked cohort mean of one uplink, reduced straight to a flat
-        ``(P,)`` buffer (quantized to ``cfg.aggregate_dtype`` first, like
-        every aggregation path).  jnp path: ``x`` is a (C, *shape) leaf
-        tree — contract per leaf and concatenate only the tiny means
-        (materializing the full (C, P) plane costs more than it saves).
-        Kernel path: ``x`` IS a (C, P) plane — one contraction.  ``None``
-        passes through (planes that were never materialized)."""
+        """Masked cohort mean of one ``(C, P)`` uplink plane for a
+        ``server_fn`` escape-hatch spec: one contraction to a flat ``(P,)``
+        buffer (quantized to ``cfg.aggregate_dtype`` first, like every
+        aggregation path).  ``None`` passes through (planes that were
+        never materialized)."""
         if x is None:
             return None
-        cfg = self.cfg
-        agg_dt = jnp.dtype(getattr(cfg, "aggregate_dtype", "float32"))
-
-        def leaf_mean(a):
-            # max(n, 1) guards the empty cohort (0/0 → NaN would poison
-            # params); exact for n ≥ 1, so non-empty rounds are bitwise
-            return (
-                jnp.tensordot(w.astype(agg_dt), a.astype(agg_dt), axes=(0, 0))
-                .astype(jnp.float32) / jnp.maximum(n_active, 1.0)
-            )
-
-        if cfg.use_fused_kernel:  # (C, P) plane
-            return leaf_mean(x)
-        return ravel_leaves(
-            [leaf_mean(l) for l in jax.tree_util.tree_leaves(x)], jnp.float32
+        agg_dt = jnp.dtype(getattr(self.cfg, "aggregate_dtype", "float32"))
+        # max(n, 1) guards the empty cohort (0/0 → NaN would poison
+        # params); exact for n ≥ 1, so non-empty rounds are bitwise
+        return (
+            jnp.tensordot(w.astype(agg_dt), x.astype(agg_dt), axes=(0, 0))
+            .astype(jnp.float32) / jnp.maximum(n_active, 1.0)
         )
 
     # -------------------------------------------------- uplink compression
@@ -1302,7 +1212,7 @@ class FederatedEngine:
         tracing anything — compression-free programs stay f32-bitwise the
         pre-compression engine's.
 
-        Kernel-fold path: int8/bf16 planes come back as :class:`QPlane`
+        Kernel fold: int8/bf16 planes come back as :class:`QPlane`
         and reach the fold COMPRESSED (the fused dequant kernel consumes
         them; under cohort sharding the ``all_to_all`` then moves the
         int8/bf16 payload).  ``state_delta`` is additionally needed dense
@@ -1315,43 +1225,18 @@ class FederatedEngine:
         residual would bias the stored state — the registry refuses specs
         declaring it).
 
-        jnp/server_fn paths: every wire plane round-trips through its
-        wire representation to dense (what arrived on the wire IS what
-        the oracle folds) and downstream code runs unchanged.  ``w`` is
-        the post-fault weight row (padded under sharding) gating the
-        error-feedback update: a client that did not transmit keeps its
-        residual."""
+        ``server_fn`` escape hatch: every wire plane round-trips through
+        its wire representation to dense (what arrived on the wire IS what
+        the jnp reduction folds).  ``w`` is the post-fault weight row
+        (padded under sharding) gating the error-feedback update: a client
+        that did not transmit keeps its residual."""
         comp = self.compression
         if comp is None:
             return outs, None
-        cfg, algo = self.cfg, self.algo
+        algo = self.algo
         wire = algo.wire_uplink_planes
         key = round_key(comp, t)
-        kernel_fold = cfg.use_fused_kernel and algo.server_fn is None
-
-        if not cfg.use_fused_kernel:
-            # jnp path: planes are (C, leaf…) trees — encode/decode on the
-            # flat representation, hand the dense trees back
-            planes = {}
-            new_rows = None
-            for name in ("delta", "state_delta", "extra"):
-                tv = getattr(outs, name)
-                if tv is None or name not in wire:
-                    continue
-                plane = spec.ravel(tv, batch_dims=1)
-                if comp.kind == "topk":
-                    if name != "delta":
-                        continue  # non-delta wire planes ride f32
-                    _, recon, new_rows = error_feedback_topk(
-                        comp, plane, residual_rows, w, spec.size
-                    )
-                    dense = recon
-                else:
-                    dense = decompress_plane(
-                        compress_plane(comp, plane, plane_key(key, name))
-                    )
-                planes[name] = spec.unravel(dense)
-            return outs._replace(**planes), new_rows
+        kernel_fold = algo.server_fn is None
 
         planes = {}
         new_rows = None
@@ -1415,14 +1300,14 @@ class FederatedEngine:
         x_t = fstate.params  # (P,) f32
         m_t = fstate.server.momentum  # (P,) momentum_dtype
         if cohort_rows is not None:
-            outs, losses, cohort_cst, cohort_cst_tree = self._flat_cohort_pass(
+            outs, losses, cohort_cst = self._flat_cohort_pass(
                 fstate, batches, ids, mask, full_batches, spec, m_t, eta_l,
                 cohort_rows=cohort_rows,
             )
         else:
             cohort_pass = (self._sharded_cohort_pass if self._sharded
                            else self._flat_cohort_pass)
-            outs, losses, cohort_cst, cohort_cst_tree = cohort_pass(
+            outs, losses, cohort_cst = cohort_pass(
                 fstate, batches, ids, mask, full_batches, spec, m_t, eta_l
             )
 
@@ -1440,7 +1325,7 @@ class FederatedEngine:
         # cohort-parallel: pad rows carry zero weight — trailing +0.0
         # terms keep every reduction bitwise the unsharded one's
         wp = self._pad_cohort(w, mode="zero") if self._sharded else w
-        use_kernel = cfg.use_fused_kernel and algo.server_fn is None
+        use_kernel = algo.server_fn is None
 
         # wire encoding between fault injection and fold — untraced when
         # cfg.compression is None (see _compress_uplink); under sharding
@@ -1449,8 +1334,7 @@ class FederatedEngine:
         if self.compression is not None:
             res_rows = self._residual_rows_for(fstate, ids, residual_rows)
             outs, new_res_rows = self._compress_uplink(
-                fstate.server.round, outs,
-                wp if cfg.use_fused_kernel else w, res_rows, spec,
+                fstate.server.round, outs, wp, res_rows, spec,
             )
 
         fsrv = fstate.server
@@ -1466,7 +1350,7 @@ class FederatedEngine:
                 )
                 new_server = new_server._replace(round=fsrv.round + 1)
             else:
-                if self._sharded:  # kernel-path spec with a server_fn escape
+                if self._sharded:  # a spec with a server_fn escape hatch
                     mean_delta, mean_sd, mean_extra = self._sharded_means(
                         outs, wp, n_active
                     )
@@ -1494,39 +1378,18 @@ class FederatedEngine:
         w_sc = w * ok.astype(jnp.float32)
 
         # scatter updated client states back (only active cohort members):
-        # ONE scatter on the (N, P) plane (kernel path; sharded planes are
-        # padded — only real rows scatter) or per-leaf like the tree
-        # oracle (jnp path).  Store-backed (emit_rows): the SAME per-row
+        # ONE scatter on the (N, P) plane (sharded planes are padded — only
+        # real rows scatter).  Store-backed (emit_rows): the SAME per-row
         # update, emitted as (C, P) rows for the host scatter instead.
         new_cst = fstate.client_states
         rows_out = None
         if algo.needs_client_state:
+            C = ids.shape[0]
+            upd = cohort_cst + outs.state_delta[:C] * w_sc[:, None]
             if emit_rows:
-                if cfg.use_fused_kernel:
-                    rows_out = cohort_cst + outs.state_delta * w_sc[:, None]
-                else:
-                    upd = jax.tree_util.tree_map(
-                        lambda a, d: a + d * w_sc.reshape(
-                            (-1,) + (1,) * (d.ndim - 1)
-                        ).astype(a.dtype),
-                        cohort_cst_tree, outs.state_delta,
-                    )
-                    rows_out = spec.ravel(upd, batch_dims=1)
-            elif self._sharded:
-                C = ids.shape[0]
-                upd = cohort_cst + outs.state_delta[:C] * w_sc[:, None]
-                new_cst = fstate.client_states.at[ids].set(upd)
-            elif cfg.use_fused_kernel:  # (N, P) plane representation
-                upd = cohort_cst + outs.state_delta * w_sc[:, None]
-                new_cst = fstate.client_states.at[ids].set(upd)
+                rows_out = upd
             else:
-                def scatter(a, d):
-                    upd = a[ids] + d * w_sc.reshape((-1,) + (1,) * (d.ndim - 1)).astype(a.dtype)
-                    return a.at[ids].set(upd)
-
-                new_cst = jax.tree_util.tree_map(
-                    scatter, fstate.client_states, outs.state_delta
-                )
+                new_cst = fstate.client_states.at[ids].set(upd)
 
         # the error-feedback residual is CLIENT-side state: it tracks what
         # the client did not transmit, so it updates whenever the client
@@ -2065,13 +1928,8 @@ class FederatedEngine:
                              residual_rows=None):
         """Client phase of one pipelined iteration: run the cohort against
         (current params, stale momentum) and pack its uplink as a ring
-        entry.  Kernel path: outputs already ARE ``(C, P)`` planes and ride
-        raw (the fused server kernel wants the cohort axis).  jnp path:
-        ``delta``/``extra`` are pre-reduced HERE to the fold-ready ``(P,)``
-        masked means — the weights are launch-time constants, so this is
-        the fold's exact value, computed by the exact sync reduction
-        (``_masked_pmean``); only the per-client ``state_delta`` plane must
-        survive to fold time (the scatter is per-client).
+        entry.  The outputs already ARE ``(C, P)`` planes and ride raw (the
+        fused server kernel wants the cohort axis).
 
         Returns (entry, n_active, cohort masked-mean loss, n_dropped,
         n_quarantined) — the fault counters of the launched cohort (the
@@ -2086,20 +1944,19 @@ class FederatedEngine:
         cfg, algo = self.cfg, self.algo
         eta_l = local_learning_rate(cfg, fstate.server.round)
         if cohort_rows is not None:  # store-backed: pre-gathered host rows
-            outs, losses, _, _ = self._flat_cohort_pass(
+            outs, losses, _ = self._flat_cohort_pass(
                 fstate, batches, ids, mask, full, spec, m_used, eta_l,
                 cohort_rows=cohort_rows,
             )
         else:
             cohort_pass = (self._sharded_cohort_pass if self._sharded
                            else self._flat_cohort_pass)
-            outs, losses, _, _ = cohort_pass(
+            outs, losses, _ = cohort_pass(
                 fstate, batches, ids, mask, full, spec, m_used, eta_l
             )
         # faults hit the uplink AT LAUNCH (drops/corruption happen on the
         # wire, not in the ring): the quarantined/thinned planes then ride
-        # the ring D−1 rounds to their fold, and the jnp pre-reduction
-        # below sees the already-sanitized payload
+        # the ring D−1 rounds to their fold
         mask, outs, n_dropped, n_quar = self._inject_faults(
             fstate.server.round, ids, mask, outs
         )
@@ -2115,25 +1972,13 @@ class FederatedEngine:
         if self.compression is not None:
             res_rows = self._residual_rows_for(fstate, ids, residual_rows)
             outs, new_res_rows = self._compress_uplink(
-                fstate.server.round, outs,
-                wp if cfg.use_fused_kernel else w, res_rows, spec,
-                ring=True,
+                fstate.server.round, outs, wp, res_rows, spec, ring=True,
             )
 
-        if cfg.use_fused_kernel:
-            delta_e, extra_e = outs.delta, outs.extra
-        else:
-            delta_e = self._masked_pmean(outs.delta, w, n_active)
-            extra_e = self._masked_pmean(outs.extra, w, n_active)
-        state_e = None
-        if outs.state_delta is not None:
-            state_e = (outs.state_delta if cfg.use_fused_kernel
-                       else spec.ravel(outs.state_delta, batch_dims=1))
-
         entry = CohortUplink(
-            delta=delta_e,
-            state_delta=state_e,
-            extra=extra_e,
+            delta=outs.delta,
+            state_delta=outs.state_delta,
+            extra=outs.extra,
             ids=(self._pad_cohort(ids) if self._sharded else ids).astype(jnp.int32),
             w=wp,
             eta_l=eta_l,
@@ -2172,7 +2017,7 @@ class FederatedEngine:
         n_active = jnp.sum(w)
         x_t = fstate.params
         fsrv = fstate.server
-        use_kernel = cfg.use_fused_kernel and algo.server_fn is None
+        use_kernel = algo.server_fn is None
 
         with jax.named_scope(FOLD_SCOPE):
             if use_kernel and self._sharded:
@@ -2192,29 +2037,13 @@ class FederatedEngine:
                     mean_delta, mean_sd, mean_extra = self._sharded_means(
                         entry, w, n_active
                     )
-                elif cfg.use_fused_kernel:
-                    # kernel-path algorithm whose round-close is a ``server_fn``
-                    # escape hatch: reduce the raw (C, P) planes exactly as the
-                    # sync kernel path does
+                else:
+                    # a spec whose round-close is a ``server_fn`` escape
+                    # hatch: reduce the raw (C, P) planes exactly as the
+                    # sync round does
                     mean_delta = self._masked_pmean(entry.delta, w, n_active)
                     mean_sd = self._masked_pmean(entry.state_delta, w, n_active)
                     mean_extra = self._masked_pmean(entry.extra, w, n_active)
-                else:
-                    # jnp path: delta/extra were pre-reduced at launch (the
-                    # weights are launch-time constants — same value, same
-                    # reduction, C× less ring state); only the per-client
-                    # state plane still needs its mean, reduced per leaf VIEW
-                    # so the contraction shapes match the sync round's exactly
-                    # (one plane-wide tensordot schedules its accumulation
-                    # differently and would break D=1 bitwise equality)
-                    mean_delta = entry.delta
-                    mean_extra = entry.extra
-                    mean_sd = None
-                    if entry.state_delta is not None:
-                        mean_sd = self._masked_pmean(
-                            spec.unravel(entry.state_delta, dtype=jnp.float32),
-                            w, n_active,
-                        )
                 # the γ=1 sync fold stays bitwise: spec.server_update skips the
                 # statically-1.0 discount multiply
                 new_params, new_server = algo.server_update(
@@ -2245,43 +2074,17 @@ class FederatedEngine:
         new_cst = fstate.client_states
         rows_out = None
         if algo.needs_client_state:
+            # padded ring rows are dropped BEFORE the scatter: a pad id (0)
+            # colliding with a real cohort member would make the
+            # duplicate-index .set nondeterministic
+            C = cohort_capacity(cfg)
+            ids_r, w_r = entry.ids[:C], w[:C]
+            rows = fold_rows if emit_rows else fstate.client_states[ids_r]
+            upd = rows + sd_e[:C] * w_r[:, None]
             if emit_rows:
-                if cfg.use_fused_kernel:
-                    rows_out = fold_rows + sd_e * w[:, None]
-                else:
-                    gathered = spec.unravel(fold_rows)
-                    sd_tree = spec.unravel(sd_e, dtype=jnp.float32)
-                    upd = jax.tree_util.tree_map(
-                        lambda a, d: a + d * w.reshape(
-                            (-1,) + (1,) * (d.ndim - 1)
-                        ).astype(a.dtype),
-                        gathered, sd_tree,
-                    )
-                    rows_out = spec.ravel(upd, batch_dims=1)
-            elif self._sharded:
-                # padded ring rows are dropped BEFORE the scatter: a pad
-                # id (0) colliding with a real cohort member would make
-                # the duplicate-index .set nondeterministic
-                C = cohort_capacity(cfg)
-                ids_r, w_r = entry.ids[:C], w[:C]
-                upd = (fstate.client_states[ids_r]
-                       + sd_e[:C] * w_r[:, None])
-                new_cst = fstate.client_states.at[ids_r].set(upd)
-            elif cfg.use_fused_kernel:  # (N, P) plane: ONE gather + scatter
-                upd = fstate.client_states[entry.ids] + sd_e * w[:, None]
-                new_cst = fstate.client_states.at[entry.ids].set(upd)
+                rows_out = upd
             else:
-                sd_tree = spec.unravel(sd_e, dtype=jnp.float32)
-
-                def scatter(a, d):
-                    upd = a[entry.ids] + d * w.reshape(
-                        (-1,) + (1,) * (d.ndim - 1)
-                    ).astype(a.dtype)
-                    return a.at[entry.ids].set(upd)
-
-                new_cst = jax.tree_util.tree_map(
-                    scatter, fstate.client_states, sd_tree
-                )
+                new_cst = fstate.client_states.at[ids_r].set(upd)
 
         new_state = FedState(new_params, new_server, new_cst, fstate.rng,
                              residuals=fstate.residuals)

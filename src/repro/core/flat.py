@@ -6,7 +6,7 @@ Every update FedCM (and each registered algorithm — see
 mean, the server momentum/param step — is elementwise over the parameter
 vector.  The pytree structure only matters to the *loss function*; carrying
 it through the update phase costs a tree_map dispatch per leaf per op and,
-on the fused-kernel path, a full concatenate/split round-trip per local
+around the fused kernels, a full concatenate/split round-trip per local
 step.  ``FlatSpec`` fixes the representation instead:
 
 * ``ravel(tree)``      → ONE contiguous ``(P,)`` buffer (default f32),
@@ -19,7 +19,7 @@ offset, size) in treedef order, with no padding between leaves.  The
 plane may end in a zero tail: ``spec.size`` is the leaves' total P,
 ``spec.plane_size`` the plane's length P' ≥ P, a multiple of the
 ``align`` the spec was built with (``FlatSpec.aligned``).  The engine
-aligns the kernel path's plane to the kernels' block length
+aligns its plane to the kernels' block length
 (``repro.kernels.plane_alignment``), so no launch pads its operands or
 slices its outputs; ``ravel`` writes the tail as zeros, ``unravel`` and
 ``view_leaf`` never read it, and every affine direction and fold row
@@ -30,16 +30,16 @@ leading batch axes reuse the same table: a cohort delta plane is
 
 ``FederatedEngine`` ravels params/momentum/client-state once per
 ``run_rounds`` call and carries the planes through the local-step scan, the
-cohort vmap, aggregation, and the server update (``cfg.use_flat_plane``;
-the tree path remains as the numerical oracle).
+cohort vmap, aggregation, and the server update (``cfg.use_flat_plane``).
+That is its one flat path; the tree path (``use_flat_plane=False``)
+remains as the numerical oracle.
 
 ``CohortUplink`` is the in-flight cohort store of the async pipelined
 engine (``FederatedEngine.run_rounds_async``): a static depth-D ring of
 uplink planes plus per-cohort metadata, carried through the pipelined
 ``lax.scan`` as a python tuple the body rotates (``ring_push``).  An
 uplink launched at round t is folded D−1 rounds later when the server
-folds the (by then stale) cohort in — the kernel path's ``(C, P)`` slot
-layout is the same layout a cohort-axis reduce-scatter wants, which is
+folds the (by then stale) cohort in — the ``(C, P)`` slot layout is the same layout a cohort-axis reduce-scatter wants, which is
 what makes the ring the natural seam for multi-host cohort sharding.
 
 Under the out-of-core population store (``cfg.population_store="host"``,
@@ -213,27 +213,18 @@ class CohortUplink(NamedTuple):
     async engine's depth-D ring carries (a python tuple of D−1 pending
     uplinks in the scan carry; the D-th is the one being launched).
 
-    Plane layout is PATH-DEPENDENT, mirroring the sync engine's own rule
-    about when the ``(C, P)`` cohort plane is worth materializing:
-
-    * kernel path (``use_fused_kernel``): ``delta``/``extra`` are raw
-      ``(C, P)`` planes — the fused server kernel folds mean + EMA + param
-      step in ONE streaming pass over the cohort axis at fold time.
-    * jnp path: ``delta``/``extra`` are the FOLD-READY masked means,
-      ``(P,)`` each — the mean's weights are launch-time constants, so
-      pre-reducing at launch is mathematically identical and the ring
-      carries C× less state (the sync jnp path never materializes the
-      cohort plane either; see ``flat_client_update``).
-
-    ``state_delta`` stays a raw ``(C, P)`` plane on BOTH paths: the
-    client-state scatter at fold time is inherently per-client.
-    ``state_delta``/``extra`` are ``None`` for algorithms without client
-    state / full-batch gradients — never allocated, never copied.
+    Every plane is a raw ``(C, P)`` cohort plane: the fused server kernel
+    folds mean + EMA + param step in ONE streaming pass over the cohort
+    axis at fold time, and the client-state scatter is per-client.  Under
+    compression a plane may ride as its wire representation (``QPlane``,
+    ``TopKPlane``) until the fold.  ``state_delta``/``extra`` are ``None``
+    for algorithms without client state / full-batch gradients — never
+    allocated, never copied.
     """
 
-    delta: jax.Array  # (C, P) kernel path / (P,) jnp path (pre-reduced)
+    delta: jax.Array  # (C, P)
     state_delta: Optional[jax.Array]  # (C, P) or None (SCAFFOLD/FedDyn)
-    extra: Optional[jax.Array]  # (C, P) / (P,) or None (MimeLite)
+    extra: Optional[jax.Array]  # (C, P) or None (MimeLite)
     ids: jax.Array  # (C,) int32 sampled client ids
     w: jax.Array  # (C,) f32 active-mask weights
     eta_l: jax.Array  # f32 η_l at launch (the fold must reuse it)

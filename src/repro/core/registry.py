@@ -38,8 +38,8 @@ An ``AlgorithmSpec`` declares:
     scalar`` callables — η_l decays per round and |S| is traced, so they
     resolve inside the jitted program.  A full escape hatch ``server_fn``
     (legacy ``server_update`` signature) replaces fold + post entirely;
-    such algorithms run the jnp reduction path even under
-    ``use_fused_kernel``.
+    on the flat plane such algorithms reduce their ``(C, P)`` planes in
+    jnp instead of the fold kernel.
 
 (c) **state planes** — ``needs_client_state`` / ``needs_momentum_broadcast``
     / ``needs_full_grad`` / ``needs_second_moment`` flags from which
@@ -131,7 +131,9 @@ class ClientOutputs(NamedTuple):
 
 
 def _dir_coef(c: DirCoef, cfg) -> float:
-    return float(c(cfg)) if callable(c) else float(c)
+    # a Python float by contract, resolved from static config at trace
+    # time; the static-zero tests (``c != 0.0``) refuse a traced one
+    return c(cfg) if callable(c) else c
 
 
 def _fold_coef(c: FoldCoef, cfg, eta_l, n_active):
@@ -152,8 +154,8 @@ class AlgorithmSpec(NamedTuple):
     The methods (``direction`` / ``client_finalize`` / ``server_update``)
     are the generic interpreters of the declarative fields — they are
     array-polymorphic (a bare ``(P,)`` buffer is a single-leaf pytree), so
-    the tree path and the flat plane share them verbatim.  The fused
-    kernel path consumes the SAME rows through
+    the tree path and the flat plane share them verbatim.  The flat
+    plane's kernels consume the SAME rows through
     ``kernels/fed_direction/ops.flat_direction_step`` and
     ``kernels/server_update/ops.fused_fold``.
     """

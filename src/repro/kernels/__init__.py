@@ -6,7 +6,6 @@
   staleness-discounted momentum EMA + param step in one pass
 * ``flash_attention`` — blocked online-softmax attention (GQA, sliding window)
 * ``ssd_scan``        — chunked Mamba2 SSD scan with VMEM-carried state
-* ``fedcm_update``    — RETIRED to oracle-only: ref.py pins the FedCM blend
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper; ``interpret=interpret_mode()``), ref.py (pure-jnp oracle used by
@@ -42,7 +41,7 @@ def interpret_mode() -> bool:
 
 
 def plane_alignment(size: int, n_shards: int) -> int:
-    """The length the flat plane of the kernel path is a multiple of
+    """The length the engine's flat plane is a multiple of
     (``FlatSpec.aligned``), so that no launch pads or slices it.
 
     A plane of ``size`` elements or more than one ``fed_direction`` block

@@ -16,7 +16,7 @@ One kernel body per aux arity streams each operand through VMEM exactly
 once and writes x once — 3 + n_aux HBM transfers/element total, the
 roofline floor for the op (AI ≈ 0.5 flop/byte; it is purely memory-bound).
 
-Tiling mirrors kernels/fedcm_update: the flat plane is padded to a multiple
+Tiling: the flat plane is padded to a multiple
 of ``block_elems`` and viewed as (padded//LANE, LANE) so every BlockSpec
 tile is a VMEM-resident (rows, 128) slab.  The engine's plane already is
 such a multiple (``FlatSpec.plane_size``), so there the pad has width 0,

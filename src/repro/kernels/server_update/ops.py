@@ -116,7 +116,7 @@ def fused_fold(spec, cfg, planes, wn, n_active, x, m, eta_l, discount=1.0):
     structural skips the jnp interpreter (``AlgorithmSpec.server_update``)
     applies, so the two routes stay step-for-step comparable.
 
-    Honors ``cfg.aggregate_dtype`` exactly like the jnp paths: uplink
+    Honors ``cfg.aggregate_dtype`` exactly like the jnp reductions: uplink
     planes are quantized BEFORE the reduction (the kernel body then
     accumulates in f32); only the reduction inputs are cast — the
     client-state scatter keeps the unquantized plane, as the tree oracle

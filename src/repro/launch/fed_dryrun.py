@@ -95,7 +95,9 @@ def build_and_lower(
     # to "model"-sharded params does NOT surrender cohort parallelism —
     # the tree path keeps the client axis sharded over "data" via the
     # engine's cohort-axis sharding constraints (client_sharding below)
-    # plus the batch in_shardings.
+    # plus the batch in_shardings.  The flat case lowers the engine's one
+    # path, the Pallas kernels, which take their interpreter form on a
+    # host without a TPU.
     probe_specs = param_specs(p_sds, cfg, mesh)
     flat_fallback_reason = _tensor_sharded_reason(probe_specs)
     use_flat = flat_fallback_reason is None

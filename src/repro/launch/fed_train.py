@@ -364,9 +364,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--per-round", action="store_true",
                     help="dispatch each round separately (A/B against fused scan)")
-    ap.add_argument("--fused-kernel", action="store_true",
-                    help="route the flat-plane update phase through the Pallas "
-                         "fed_direction/server_update kernels")
     ap.add_argument("--flat-plane", action=argparse.BooleanOptionalAction,
                     default=FedConfig.use_flat_plane,
                     help="carry the round state on the ravelled (P,) parameter "
@@ -408,8 +405,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cohort-shard", type=int, default=0,
                     help="shard the client axis over N devices (a "
                          "('clients',) mesh; each device runs C/N clients "
-                         "end-to-end and the fold is a reduce-scatter). "
-                         "Requires --fused-kernel; 0 = single-device")
+                         "end-to-end and the fold is a reduce-scatter); "
+                         "0 = single-device")
     # ---- fault tolerance (ISSUE PR-7): faults are CONFIG DATA ----------
     fault = ap.add_argument_group(
         "fault injection / degradation",
@@ -527,8 +524,7 @@ def resolve_config(args: argparse.Namespace) -> FedConfig:
         algo=args.algo, num_clients=args.clients, cohort_size=args.cohort,
         local_steps=args.local_steps, alpha=args.alpha, eta_l=args.eta_l,
         eta_g=args.eta_g, participation=args.participation, rounds=args.rounds,
-        seed=args.seed, use_fused_kernel=args.fused_kernel,
-        use_flat_plane=args.flat_plane,
+        seed=args.seed, use_flat_plane=args.flat_plane,
         pipeline_depth=args.pipeline_depth, staleness=args.staleness,
         staleness_discount=args.staleness_discount,
         cohort_shard=args.cohort_shard,
@@ -551,8 +547,7 @@ def _static_contracts(cfg: FedConfig, args: argparse.Namespace) -> dict:
 
     use_async = (args.async_pipeline or cfg.pipeline_depth > 1
                  or cfg.staleness > 0)
-    return quick_contracts(use_async=use_async,
-                           use_fused_kernel=cfg.use_fused_kernel)
+    return quick_contracts(use_async=use_async)
 
 
 def write_dryrun_artifact(cfg: FedConfig, args: argparse.Namespace) -> Path:
@@ -561,7 +556,6 @@ def write_dryrun_artifact(cfg: FedConfig, args: argparse.Namespace) -> Path:
     # the wiring contract, asserted here so a --dryrun in CI trips on
     # regressions even before any test reads the artifact back
     assert cfg.use_flat_plane == args.flat_plane
-    assert cfg.use_fused_kernel == args.fused_kernel
     assert cfg.pipeline_depth == args.pipeline_depth
     assert cfg.staleness == args.staleness
     assert cfg.cohort_shard == args.cohort_shard
@@ -655,10 +649,6 @@ def main(argv=None) -> int:
         ap.error("--per-round dispatches one round per jit call; the async "
                  "pipelined engine is a single fused program — drop one of "
                  "--per-round / --async / --pipeline-depth / --staleness")
-    if args.cohort_shard > 0 and not args.fused_kernel:
-        ap.error("--cohort-shard rides the flat+kernel path (clients emit "
-                 "(C, P) planes, the fold is the scattered server kernel) "
-                 "— add --fused-kernel")
     if args.cohort_shard > 0 and not args.flat_plane:
         ap.error("--cohort-shard shards the flat (C, P) uplink planes — "
                  "drop --no-flat-plane")

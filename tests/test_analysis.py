@@ -313,6 +313,6 @@ def test_collective_audit_sees_primitives():
 
 
 def test_quick_contracts_pass_on_shipped_engine():
-    sc = quick_contracts(use_async=False, use_fused_kernel=True)
+    sc = quick_contracts(use_async=False)
     assert sc["donation_ok"] and sc["transfer_guard_ok"]
     assert sc["trace_count"] == sc["trace_budget"] == TRACE_BUDGET

@@ -50,7 +50,7 @@ def test_phase_a_runs(smoke, tiny_setting, capsys):
     smoke.phase_a(failures, base=tiny_setting, chunk=2, **TINY_DATA)
     _only_kernel_checks_fail(failures)
     out = capsys.readouterr().out
-    assert "A[f32] kernel vs jnp" in out and "A[int8] kernel vs jnp" in out
+    assert "A[f32] kernel vs tree" in out and "A[int8] Mosaic vs interpreter" in out
 
 
 def test_phase_b_runs(smoke, capsys):
@@ -58,7 +58,7 @@ def test_phase_b_runs(smoke, capsys):
     mcfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), vocab_size=256)
     smoke.phase_b(failures, mcfg=mcfg, seq=32)
     _only_kernel_checks_fail(failures)
-    assert "B kernel vs jnp" in capsys.readouterr().out
+    assert "B kernel vs tree" in capsys.readouterr().out
 
 
 def test_sharded_phases_run(smoke, tiny_setting, capsys):
@@ -103,5 +103,5 @@ def test_compare_catches_a_kernel_that_drops_momentum(smoke, tiny_setting,
     else:
         mcfg = dataclasses.replace(reduced(get_config("llama3.2-1b")), vocab_size=256)
         smoke.phase_b(failures, mcfg=mcfg, seq=32)
-    caught = [f for f in failures if "kernel vs jnp: losses differ" in f]
+    caught = [f for f in failures if "kernel vs tree: losses differ" in f]
     assert caught, (failures, capsys.readouterr().out)

@@ -72,8 +72,7 @@ _LOSS = classification_loss(_MODEL.apply)
 
 def _engine(algo, n_shards, cohort=16, participation="fixed", **kw):
     cfg = FedConfig(algo=algo, num_clients=32, cohort_size=cohort,
-                    local_steps=2, participation=participation,
-                    use_fused_kernel=True, **kw)
+                    local_steps=2, participation=participation, **kw)
     mesh = make_cohort_mesh(n_shards) if n_shards else None
     eng = FederatedEngine(cfg, _LOSS, batch_size=8, cohort_mesh=mesh)
     state = eng.init(_MODEL.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
@@ -118,11 +117,8 @@ def test_padded_cohort():
 
 def test_cohort_mesh_rejects_tree_and_jnp_paths():
     mesh = make_cohort_mesh(1)
-    with pytest.raises(ValueError, match="use_fused_kernel"):
-        FederatedEngine(FedConfig(algo="fedcm"), _LOSS, cohort_mesh=mesh)
     with pytest.raises(ValueError, match="flat"):
-        FederatedEngine(FedConfig(algo="fedcm", use_flat_plane=False,
-                                  use_fused_kernel=True), _LOSS,
+        FederatedEngine(FedConfig(algo="fedcm", use_flat_plane=False), _LOSS,
                         cohort_mesh=mesh)
 
 
@@ -132,7 +128,7 @@ def test_cohort_mesh_rejects_client_sharding_combo():
     mesh = make_cohort_mesh(1)
     with pytest.raises(ValueError, match="client_sharding"):
         FederatedEngine(
-            FedConfig(algo="fedcm", use_fused_kernel=True), _LOSS,
+            FedConfig(algo="fedcm"), _LOSS,
             cohort_mesh=mesh,
             client_sharding=NamedSharding(mesh, P("clients")),
         )

@@ -9,7 +9,7 @@ Covers the tentpole layers:
 3. registry validation — lossy sparsification without a residual stream
    is refused at registration time,
 4. the engine end-to-end: compressed runs tolerance-bounded against the
-   uncompressed oracle on sync/async/kernel paths, the EF residual
+   uncompressed oracle on the sync and async engines, the EF residual
    stream checkpointing (resident + host store) and continuing bitwise
    through a kill/resume, and the double-buffered host-store loop's
    bitwise contract against the synchronous loop.
@@ -276,30 +276,26 @@ def test_engine_requires_flat_plane_for_compression():
 
 @pytest.mark.parametrize("kind", ["int8", "bf16"])
 def test_compressed_run_close_to_uncompressed_oracle(kind):
-    """Quantization noise is bounded: a compressed 3-round trajectory stays
-    within a per-round-noise tolerance of the f32 oracle, on both the jnp
-    and the fused dequant-fold routes — and the two routes agree with each
-    other to kernel noise."""
+    """Quantization noise is bounded: a compressed 3-round trajectory
+    through the fused dequant fold stays within a per-round-noise
+    tolerance of the f32 tree oracle, and the uncompressed flat run agrees
+    with that oracle to kernel noise."""
     comp = CompressionConfig(kind=kind, seed=0)
-    _, eng_f32, data, model = _setup("fedcm", use_fused_kernel=True)
+    _, eng_tree, data, model = _setup("fedcm", use_flat_plane=False)
+    st_tree, _ = eng_tree.run_rounds(_fresh_state(eng_tree, model), data, 3)
+    _, eng_f32, _, _ = _setup("fedcm")
     st_f32, _ = eng_f32.run_rounds(_fresh_state(eng_f32, model), data, 3)
-    outs = {}
-    for kernel in (True, False):
-        _, eng, data_c, _ = _setup("fedcm", use_fused_kernel=kernel,
-                                   compression=comp)
-        st, ms = eng.run_rounds(_fresh_state(eng, model), data_c, 3)
-        outs[kernel] = st
-        # loose bound: per-round rounding noise ~ scale·eta ≪ 1e-2 here
-        _assert_trees_close(st.params, st_f32.params, rtol=0.0, atol=5e-3)
-    _assert_trees_close(outs[True].params, outs[False].params,
-                        rtol=2e-5, atol=2e-6)
+    _assert_trees_close(st_f32.params, st_tree.params, rtol=2e-5, atol=2e-6)
+    _, eng, data_c, _ = _setup("fedcm", compression=comp)
+    st, ms = eng.run_rounds(_fresh_state(eng, model), data_c, 3)
+    # loose bound: per-round rounding noise ~ scale·eta ≪ 1e-2 here
+    _assert_trees_close(st.params, st_tree.params, rtol=0.0, atol=5e-3)
 
 
 def test_compression_accounting_reaches_metrics():
     P = 212  # mlp (8, 16, 4) plane
     comp = CompressionConfig(kind="int8")
-    _, eng, data, model = _setup("fedcm", compression=comp,
-                                 use_fused_kernel=True)
+    _, eng, data, model = _setup("fedcm", compression=comp)
     st, ms = eng.run_rounds(_fresh_state(eng, model), data, 2)
     per_client = int(np.asarray(ms.bytes_up)[-1]) / int(np.asarray(ms.n_active)[-1])
     assert per_client == P + 4  # int8 byte/elem + one f32 row scale
@@ -307,28 +303,33 @@ def test_compression_accounting_reaches_metrics():
 
 
 def test_async_ring_carries_compression():
-    """The async engine folds compressed in-flight cohorts: jnp and kernel
-    routes agree, and int8 stays near the f32 async oracle."""
+    """The async engine folds compressed in-flight cohorts: at depth 1 the
+    ring's int8 run matches the sync int8 run (the same wire and fold), and
+    at depth 2 int8 stays near the f32 async oracle."""
     outs = {}
     for kind in (None, "int8"):
         comp = None if kind is None else CompressionConfig(kind=kind)
-        for kernel in (True, False):
-            _, eng, data, model = _setup("fedcm", use_fused_kernel=kernel,
-                                         compression=comp)
-            st, _ = eng.run_rounds_async(_fresh_state(eng, model), data, 4,
-                                         pipeline_depth=2, staleness=1)
-            outs[(kind, kernel)] = st
-    _assert_trees_close(outs[("int8", True)].params,
-                        outs[("int8", False)].params, rtol=2e-5, atol=2e-6)
-    _assert_trees_close(outs[("int8", True)].params,
-                        outs[(None, True)].params, rtol=0.0, atol=5e-3)
+        _, eng, data, model = _setup("fedcm", compression=comp)
+        st, _ = eng.run_rounds_async(_fresh_state(eng, model), data, 4,
+                                     pipeline_depth=2, staleness=1)
+        outs[kind] = st
+    _, eng, data, model = _setup("fedcm", compression=CompressionConfig(kind="int8"))
+    st_sync, _ = eng.run_rounds(_fresh_state(eng, model), data, 4)
+    st_d1, _ = eng.run_rounds_async(_fresh_state(eng, model), data, 4,
+                                    pipeline_depth=1, staleness=0)
+    _assert_trees_close(st_d1.params, st_sync.params, rtol=2e-5, atol=2e-6)
+    _assert_trees_close(outs["int8"].params, outs[None].params,
+                        rtol=0.0, atol=5e-3)
 
 
 def test_topk_residuals_initialized_and_updated():
     comp = CompressionConfig(kind="topk", topk_frac=0.1)
     _, eng, data, model = _setup("fedcm", compression=comp)
     st = _fresh_state(eng, model)
-    assert st.residuals is not None and st.residuals.shape == (10, 212)
+    # one residual row per client, in the engine's plane layout
+    # (212 parameters and the zero tail up to the kernels' block)
+    plane = eng._flat_spec(st.params).plane_size
+    assert st.residuals is not None and st.residuals.shape == (10, plane)
     np.testing.assert_array_equal(np.asarray(st.residuals), 0.0)
     st, _ = eng.run_rounds(st, data, 2)
     # the sampled cohort's rows accumulated unsent mass; others stayed zero

@@ -115,14 +115,14 @@ def test_fault_draws_are_reproducible_and_slot_independent():
             or not np.array_equal(np.asarray(a.drop), np.asarray(e.drop)))
 
 
-@pytest.mark.parametrize("kernel", [False, True])
-def test_lossy_uplink_run_stays_finite(kernel):
+@pytest.mark.parametrize("flat_plane", [True, False])
+def test_lossy_uplink_run_stays_finite(flat_plane):
     """The acceptance scenario: 20% drops + 1% NaN corruption, fedcm —
-    the run completes finite on the jnp and kernel paths."""
+    the run completes finite on the flat engine and the tree oracle."""
     fault = FaultConfig(drop_rate=0.2, corrupt_rate=0.01, seed=0)
     _, eng, data, model = _setup("fedcm", num_clients=20, cohort_size=8,
                                  participation="bernoulli", fault=fault,
-                                 min_quorum=2, use_fused_kernel=kernel)
+                                 min_quorum=2, use_flat_plane=flat_plane)
     st, ms = eng.run_rounds(_fresh_state(eng, model), data, 8)
     assert _all_finite(st.params)
     assert _all_finite(st.server)
@@ -198,14 +198,14 @@ def test_min_quorum_skips_the_fold():
                         jax.tree_util.tree_map(jnp.zeros_like, st.server.momentum))
 
 
-@pytest.mark.parametrize("kernel", [False, True])
-def test_empty_cohort_round_is_a_guarded_noop(kernel):
-    """The empty-cohort hazard (satellite a): an all-dropped cohort used
-    to masked-mean 0/0 → NaN params.  With allow_empty_cohort the round
-    must be a finite no-op on BOTH the jnp and kernel paths."""
+@pytest.mark.parametrize("flat_plane", [True, False])
+def test_empty_cohort_round_is_a_guarded_noop(flat_plane):
+    """The empty-cohort hazard: an all-dropped cohort used to masked-mean
+    0/0 → NaN params.  With allow_empty_cohort the round must be a finite
+    no-op on BOTH the flat engine and the tree oracle."""
     _, eng, data, model = _setup("fedcm", dropout_rate=1.0,
                                  allow_empty_cohort=True,
-                                 use_fused_kernel=kernel)
+                                 use_flat_plane=flat_plane)
     st0 = _fresh_state(eng, model)
     p0 = jax.tree_util.tree_map(lambda l: np.asarray(l), st0.params)
     st, ms = eng.run_rounds(st0, data, 2)

@@ -37,29 +37,23 @@ def test_async_flags_wire_through():
     assert _resolved([]).pipeline_depth == 1 and _resolved([]).staleness == 0
 
 
-def test_fused_kernel_flag_wires_through():
-    assert _resolved([]).use_fused_kernel is False
-    assert _resolved(["--fused-kernel"]).use_fused_kernel is True
-
-
 def test_cohort_shard_flag_wires_through():
     assert _resolved([]).cohort_shard == 0
-    cfg = _resolved(["--cohort-shard", "4", "--fused-kernel"])
+    cfg = _resolved(["--cohort-shard", "4"])
     assert cfg.cohort_shard == 4 and cfg.use_fused_kernel is True
 
 
 def test_cohort_shard_requires_kernel_and_flat_plane():
-    with pytest.raises(SystemExit):  # argparse error: needs --fused-kernel
-        main(["--dryrun", "--cohort-shard", "2"])
-    with pytest.raises(SystemExit):  # and the flat plane
-        main(["--dryrun", "--cohort-shard", "2", "--fused-kernel",
-              "--no-flat-plane"])
+    with pytest.raises(SystemExit):  # the kernels take no flag any more
+        main(["--dryrun", "--cohort-shard", "2", "--fused-kernel"])
+    with pytest.raises(SystemExit):  # the flat plane is required
+        main(["--dryrun", "--cohort-shard", "2", "--no-flat-plane"])
 
 
 def test_cohort_shard_dryrun_records_mesh(tmp_path, monkeypatch):
     art = tmp_path / "fed_train_dryrun.json"
     monkeypatch.setattr("repro.launch.fed_train.DRYRUN_ARTIFACT", art)
-    rc = main(["--dryrun", "--cohort-shard", "2", "--fused-kernel"])
+    rc = main(["--dryrun", "--cohort-shard", "2"])
     assert rc == 0
     got = json.loads(art.read_text())
     assert got["resolved_config"]["cohort_shard"] == 2
@@ -75,7 +69,7 @@ def test_cohort_shard_dryrun_records_mesh(tmp_path, monkeypatch):
 def test_dryrun_artifact_records_resolved_config(tmp_path, monkeypatch):
     art = tmp_path / "fed_train_dryrun.json"
     monkeypatch.setattr("repro.launch.fed_train.DRYRUN_ARTIFACT", art)
-    rc = main(["--dryrun", "--no-flat-plane", "--fused-kernel",
+    rc = main(["--dryrun", "--no-flat-plane",
                "--pipeline-depth", "2", "--staleness", "1",
                "--algo", "scaffold", "--clients", "7"])
     assert rc == 0

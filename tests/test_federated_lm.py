@@ -50,20 +50,21 @@ def test_federated_llm_example_runs_two_rounds():
 
 def test_lm_client_kernel_path_matches_jnp():
     """Phase B of chip_smoke.py at a reduced width: the Pallas (interpret)
-    round against the plain jnp round, per-round losses and params."""
+    round against the tree oracle's plain jnp round, per-round losses and
+    params."""
     mcfg = replace(reduced(get_config("llama3.2-1b")), vocab_size=256)
     model = build_model(mcfg)
     data = FederatedTokens.from_sequences(
         make_federated_lm_corpus(mcfg.vocab_size, 4, 8, 33, seed=1)
     )
     out = {}
-    for kernel in (True, False):
+    for flat in (True, False):
         cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=2,
                         participation="fixed", local_steps=2, rounds=2,
-                        use_fused_kernel=kernel)
+                        use_flat_plane=flat)
         eng = FederatedEngine(cfg, federated_lm_loss(model), batch_size=2)
         state = eng.init(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
-        out[kernel] = eng.run_rounds(state, data, 2)
+        out[flat] = eng.run_rounds(state, data, 2)
     (s_k, m_k), (s_j, m_j) = out[True], out[False]
     np.testing.assert_allclose(np.asarray(m_k.loss), np.asarray(m_j.loss),
                                rtol=1e-5)

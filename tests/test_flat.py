@@ -8,7 +8,7 @@
 * Donation: run_rounds donates its input state; the returned trajectory
   must be stable when the donated buffers get recycled by later calls.
 * Mixed bf16/f32 trees survive the flat round trip.
-* The kernel path's aligned plane: a zero tail that ``ravel`` writes,
+* The engine's aligned plane: a zero tail that ``ravel`` writes,
   ``unravel`` ignores and every algorithm keeps exactly zero, while
   payload accounting charges the leaves alone.
 * The gradient's return into the plane: ``unravel``'s transpose is one
@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import CompressionConfig, FedConfig
-from repro.core import FederatedEngine, FlatSpec, list_algorithms
+from repro.core import FederatedEngine, FlatSpec, get_algorithm, list_algorithms
+from repro.core.compress import uplink_bytes_per_client
 from repro.data import FederatedData, make_synthetic_classification
 from repro.models.small import classification_loss, mlp_classifier
 from repro.utils.trees import split_flat
@@ -333,13 +334,13 @@ def test_unravel_gradient_equals_the_slices_transpose(case):
 
 @pytest.mark.parametrize("algo", ["fedcm", "mimelite"])
 def test_kernel_path_planes_match_the_slices_transpose(algo, monkeypatch):
-    """A kernel-path ``run_rounds`` on a multi-leaf model with weight decay
+    """A ``run_rounds`` on a multi-leaf model with weight decay
     (MimeLite adds a full-batch gradient) gives the planes, momentum and
     losses that the view's plain slices give, bit for bit."""
     runs = []
     for view in (FlatSpec.unravel, _sliced_view):
         monkeypatch.setattr(FlatSpec, "unravel", view)
-        _, eng, data, model = _setup(algo, use_fused_kernel=True, weight_decay=1e-3)
+        _, eng, data, model = _setup(algo, weight_decay=1e-3)
         runs.append(eng.run_rounds(_fresh(eng, model), data, N_ROUNDS))
     (got, m_got), (want, m_want) = runs
     for a, b in ((got.params, want.params), (got.server.momentum, want.server.momentum)):
@@ -350,7 +351,7 @@ def test_kernel_path_planes_match_the_slices_transpose(algo, monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# the kernel path's aligned plane
+# the engine's aligned plane
 # ----------------------------------------------------------------------
 
 
@@ -374,10 +375,10 @@ _TAIL_CASES = [pytest.param(a, {}, "sync", id=a) for a in list_algorithms()] + [
 
 @pytest.mark.parametrize("algo,kw,mode", _TAIL_CASES)
 def test_kernel_path_plane_tail_stays_zero(algo, kw, mode):
-    """Every plane the kernel path carries keeps its zero tail exactly
+    """Every plane the flat engine carries keeps its zero tail exactly
     zero through several rounds: x, m, the client state, the top-k
     residuals and, on the async ring, the in-flight uplinks."""
-    _, eng, data, model = _setup(algo, use_fused_kernel=True, **kw)
+    _, eng, data, model = _setup(algo, **kw)
     st = _fresh(eng, model)
     spec = eng._flat_spec(st.params)
     assert spec.plane_size == 4096 > spec.size  # 212 leaves, one tile
@@ -402,17 +403,21 @@ def test_kernel_path_plane_tail_stays_zero(algo, kw, mode):
 
 @pytest.mark.parametrize("kind", [None, "int8", "topk"])
 def test_aligned_plane_charges_the_leaves(kind):
-    """Payload accounting charges the leaves' P, not the kernel path's P'."""
+    """Payload accounting charges the leaves' P, not the aligned plane's
+    P': scaffold sends x and c down and two wire planes up."""
     comp = None if kind is None else CompressionConfig(kind=kind, topk_frac=0.1)
-    bytes_up = {}
-    for kernel in (False, True):
-        _, eng, data, model = _setup("scaffold", use_fused_kernel=kernel,
-                                     compression=comp)
-        st = _fresh(eng, model)
-        payload = eng.payload_bytes(st.params)
-        _, ms = eng.run_rounds(st, data, 1)
-        bytes_up[kernel] = (float(ms.bytes_up[0]), float(ms.bytes_down[0]), payload)
-    assert bytes_up[True] == bytes_up[False]
+    _, eng, data, model = _setup("scaffold", compression=comp)
+    st = _fresh(eng, model)
+    leaves = FlatSpec.from_tree(st.params)
+    assert eng._flat_spec(st.params).plane_size > leaves.size
+    wire = get_algorithm("scaffold").wire_uplink_planes
+    up = (len(wire) * leaves.nbytes if comp is None else
+          uplink_bytes_per_client(comp, wire, leaves.size, leaves.nbytes))
+    down = 2 * leaves.nbytes
+    assert eng.payload_bytes(st.params) == {"down_per_client": down, "up_per_client": up}
+    _, ms = eng.run_rounds(st, data, 1)
+    n = float(ms.n_active[0])
+    assert (float(ms.bytes_up[0]), float(ms.bytes_down[0])) == (n * up, n * down)
 
 
 # ----------------------------------------------------------------------

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from repro.kernels.fed_direction.kernel import fed_direction_flat
 from repro.kernels.fed_direction.ops import flat_direction_step
 from repro.kernels.fed_direction.ref import fed_direction_ref
-from repro.kernels.fedcm_update.ref import fedcm_step_ref
+from repro.kernels.fed_direction.ref import fedcm_step_ref
 from repro.kernels.server_update.ops import fused_server_step
 from repro.kernels.server_update.ref import server_update_ref
 from repro.kernels.flash_attention.ops import flash_attention
@@ -26,10 +26,9 @@ def _tol(dtype):
 
 
 # ----------------------------------------------------------------------
-# fedcm blend oracle (legacy fedcm_update kernel retired to ref-only: the
-# blend now launches through fed_direction with coefs (η, α, 0, 1−α) —
-# these tests pin that route to Algorithm 2 line 8–9 via the RETAINED
-# fedcm_step_ref oracle, independent of fed_direction's own reference)
+# fedcm blend oracle: the blend launches through fed_direction with coefs
+# (η, α, 0, 1−α) — these tests pin that route to Algorithm 2 line 8–9 via
+# fedcm_step_ref, independent of fed_direction's generic reference
 # ----------------------------------------------------------------------
 
 

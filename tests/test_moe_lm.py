@@ -205,7 +205,7 @@ DISPATCH, EXPERTS = "moe.dispatch", "moe.experts"
 def _round_hlo(model_cfg, vocab):
     model = build_model(model_cfg)
     cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=2, local_steps=2,
-                    participation="fixed", use_fused_kernel=True)
+                    participation="fixed")
     eng = FederatedEngine(cfg, federated_lm_loss(model), batch_size=2)
     state = eng.init(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
     tokens = jnp.zeros((4, 4, 16), jnp.int32) + (jnp.arange(16) % vocab)

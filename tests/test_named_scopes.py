@@ -62,7 +62,7 @@ def _engine(**kw):
         _DATA["model"] = mlp_classifier((8, 16, 4))
     model = _DATA["model"]
     cfg = FedConfig(**{"algo": "fedcm", "num_clients": 8, "cohort_size": 4, "local_steps": 2,
-                       "use_fused_kernel": True, "weight_decay": 1e-3, **kw})
+                       "weight_decay": 1e-3, **kw})
     eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=8)
     state = eng.init(model.init(jax.random.PRNGKey(0)), jax.random.PRNGKey(1))
     d = _DATA["d"]

@@ -6,13 +6,12 @@ The contract under test (ISSUE: million-client population engine):
   runs the SAME jitted round functions as the resident engine,
   parameterized by host-gathered ``(C, P)`` rows — so at matched cohorts
   the trajectories agree bitwise with the per-round resident oracle
-  (``run_round`` × n) on the sync engine (jnp AND kernel paths) and with
-  ``run_rounds_async`` on the kernel path (Pallas pins the op order).
-  The async jnp path is held to tight f32 tolerance instead: the resident
-  async engine is ONE scanned program and XLA's fusion choices across the
-  scan boundary reassociate its jnp reductions at the ulp level — the
-  same reason the repo holds ``run_rounds`` vs sequential ``run_round``
-  to tolerance rather than bitwise.
+  (``run_round`` × n) on the sync engine and with ``run_rounds_async``
+  (Pallas pins the op order).  The async run is also held to tight f32
+  tolerance, a bound that holds even where XLA's fusion choices across
+  the resident scan boundary reassociate a reduction at the ulp level —
+  the same reason the repo holds ``run_rounds`` vs sequential
+  ``run_round`` to tolerance rather than bitwise.
 * **No (N, ·) device plane** ever exists on the host path; host memory
   scales with TOUCHED clients.
 * **Uniform availability is the legacy sampler, verbatim** (same key
@@ -80,9 +79,8 @@ def _store_rows_vs_resident(eng_host, resident_state):
 
 
 @pytest.mark.parametrize("algo", ["scaffold", "feddyn"])
-@pytest.mark.parametrize("kernel", [False, True])
-def test_store_sync_bitwise_vs_resident(algo, kernel):
-    cfg, loss_fn, data, model = _setup(algo, use_fused_kernel=kernel)
+def test_store_sync_bitwise_vs_resident(algo):
+    cfg, loss_fn, data, model = _setup(algo)
     eng_r = FederatedEngine(cfg, loss_fn, batch_size=8)
     sr = _fresh(eng_r, model)
     losses = []
@@ -103,8 +101,7 @@ def test_store_sync_bitwise_vs_resident(algo, kernel):
 
 @pytest.mark.parametrize("algo", ["scaffold", "feddyn"])
 def test_store_async_kernel_bitwise_vs_resident(algo):
-    cfg, loss_fn, data, model = _setup(
-        algo, use_fused_kernel=True, pipeline_depth=2, staleness=1)
+    cfg, loss_fn, data, model = _setup(algo, pipeline_depth=2, staleness=1)
     eng_r = FederatedEngine(cfg, loss_fn, batch_size=8)
     sr, mr = eng_r.run_rounds_async(_fresh(eng_r, model), data, 6)
 
@@ -121,10 +118,10 @@ def test_store_async_kernel_bitwise_vs_resident(algo):
 
 
 @pytest.mark.parametrize("algo", ["scaffold", "feddyn"])
-def test_store_async_jnp_matches_resident_tight(algo):
-    # jnp path: same host-loop schedule (the kernel test above pins it
-    # bitwise), but XLA refuses to reassociate identically across the
-    # resident scan boundary — hold the trajectory to f32-noise tolerance
+def test_store_async_matches_resident_tight(algo):
+    # the same host-loop schedule as the bitwise test above, held to
+    # f32-noise tolerance: the bound that survives XLA reassociating a
+    # reduction differently across the resident scan boundary
     cfg, loss_fn, data, model = _setup(algo, pipeline_depth=2, staleness=1)
     eng_r = FederatedEngine(cfg, loss_fn, batch_size=8)
     sr, mr = eng_r.run_rounds_async(_fresh(eng_r, model), data, 6)
@@ -374,12 +371,11 @@ def test_host_store_streaming_end_to_end_bounded_memory():
 @pytest.mark.slow
 def test_host_store_1e5_smoke():
     # the multidevice CI job's N=1e5 participation smoke: a store-backed
-    # kernel-path zipf run must hold rounds without materializing the
+    # zipf run must hold rounds without materializing the
     # population (device OR host)
     cfg = FedConfig(algo="scaffold", num_clients=100_000, cohort_size=20,
                     local_steps=2, participation="bernoulli",
-                    availability="zipf", use_fused_kernel=True,
-                    population_store="host")
+                    availability="zipf", population_store="host")
     task = StreamingClientData(cfg.num_clients, dim=8, n_classes=4, seed=0)
     model = mlp_classifier((8, 16, 4))
     eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=8)

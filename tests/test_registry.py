@@ -132,7 +132,7 @@ def _close(a, b, atol=1e-5, rtol=1e-5):
 
 def test_custom_affine_spec_runs_every_path():
     """A brand-new affine spec (declared as pure data) passes the same
-    flat-vs-tree / kernel / async-bitwise contracts as the builtins."""
+    flat-vs-tree / async-bitwise contracts as the builtins."""
     register_algorithm(AlgorithmSpec(
         name="_test_damped",
         # damped SGD with a proximal pull toward the round anchor
@@ -145,15 +145,11 @@ def test_custom_affine_spec_runs_every_path():
         cfg, eng, data, model = _toy_setup("_test_damped")
         eng_tree = FederatedEngine(replace(cfg, use_flat_plane=False),
                                    eng.loss_fn, batch_size=8)
-        eng_k = FederatedEngine(replace(cfg, use_fused_kernel=True),
-                                eng.loss_fn, batch_size=8)
         s_f, _ = eng.run_rounds(_fresh(eng, model), data, 3)
         s_t, _ = eng_tree.run_rounds(_fresh(eng_tree, model), data, 3)
-        s_k, _ = eng_k.run_rounds(_fresh(eng_k, model), data, 3)
         s_a, _ = eng.run_rounds_async(_fresh(eng, model), data, 3,
                                       pipeline_depth=1, staleness=0)
         _close(s_f.params, s_t.params)
-        _close(s_f.params, s_k.params)
         for a, b in zip(jax.tree_util.tree_leaves(s_f.params),
                         jax.tree_util.tree_leaves(s_a.params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -163,8 +159,8 @@ def test_custom_affine_spec_runs_every_path():
 
 def test_custom_escape_hatch_spec_runs_both_paths():
     """Full escape hatches (non-affine direction_fn + server_fn) still ride
-    every engine path; under use_fused_kernel the server falls back to the
-    jnp reduction of the (C, P) planes."""
+    every engine path; on the flat plane the server takes the jnp
+    reduction of the (C, P) planes instead of the fold kernel."""
     def sign_dir(cfg, m, cst, x, x0, g):
         return jax.tree_util.tree_map(jnp.sign, g)
 
@@ -181,13 +177,9 @@ def test_custom_escape_hatch_spec_runs_both_paths():
         cfg, eng, data, model = _toy_setup("_test_signsgd")
         eng_tree = FederatedEngine(replace(cfg, use_flat_plane=False),
                                    eng.loss_fn, batch_size=8)
-        eng_k = FederatedEngine(replace(cfg, use_fused_kernel=True),
-                                eng.loss_fn, batch_size=8)
         s_f, m_f = eng.run_rounds(_fresh(eng, model), data, 3)
         s_t, _ = eng_tree.run_rounds(_fresh(eng_tree, model), data, 3)
-        s_k, _ = eng_k.run_rounds(_fresh(eng_k, model), data, 3)
         _close(s_f.params, s_t.params)
-        _close(s_f.params, s_k.params)
         assert np.all(np.isfinite(np.asarray(m_f.loss)))
     finally:
         unregister_algorithm("_test_signsgd")

@@ -6,12 +6,12 @@
   round-step implementation, so the tolerance is tight.
 * compile-count: N rounds execute as ONE trace of the scanned program, and
   a second call with the same shapes does not retrace.
-* fused Pallas kernel path (cfg.use_fused_kernel): matches the unfused
+* the flat engine (Pallas kernels): matches the tree oracle's unfused
   tree_map arithmetic (ref.py is the kernel's own oracle in test_kernels).
 * client_sharding: constraining the cohort axis changes nothing numerically.
 * async pipelined engine (run_rounds_async): the degenerate schedule
   (pipeline_depth=1, staleness=0) must be EXACTLY run_rounds — f32
-  bitwise — for every algorithm on both the jnp and kernel paths; depth>1
+  bitwise — for every algorithm; depth>1
   fills/folds/drains correctly; staleness>0 still converges on a
   heterogeneous quadratic toy problem.
 * bf16 master plane: sequential run_round and fused run_rounds share the
@@ -116,7 +116,7 @@ def test_run_rounds_rejects_nonpositive():
 
 
 # an MLP whose 69,804 parameters pass one fed_direction block (65,536) and
-# are no multiple of it: the kernel path's plane aligns to 131,072
+# are no multiple of it: the engine's plane aligns to 131,072
 LONG_PLANE = (8, 300, 220, 4)
 
 
@@ -126,11 +126,11 @@ LONG_PLANE = (8, 300, 220, 4)
 ])
 def test_fused_kernel_path_matches_reference(algo, widths):
     """Flat engine + Pallas kernels (fed_direction local steps, fused
-    server fold-row passes + pure post-steps) vs the unfused jnp flat
-    path — for EVERY registered algorithm (the registry parametrizes),
-    and on a plane past one direction block that the kernel path aligns."""
-    cfg, eng, data, model = _setup(algo, widths)
-    engk = FederatedEngine(replace(cfg, use_fused_kernel=True), eng.loss_fn, batch_size=8)
+    server fold-row passes + pure post-steps) vs the tree oracle — for
+    EVERY registered algorithm (the registry parametrizes), and on a
+    plane past one direction block that the engine aligns."""
+    cfg, engk, data, model = _setup(algo, widths)
+    eng = FederatedEngine(replace(cfg, use_flat_plane=False), engk.loss_fn, batch_size=8)
     s_ref, m_ref = eng.run_rounds(_fresh_state(eng, model), data, 3)
     s_k, m_k = engk.run_rounds(_fresh_state(engk, model), data, 3)
     _assert_trees_close(s_ref.params, s_k.params, rtol=1e-5, atol=1e-6)
@@ -142,19 +142,18 @@ def test_fused_kernel_path_matches_reference(algo, widths):
 
 def test_fused_server_kernel_honors_aggregate_dtype():
     """Regression: the fused server kernel must quantize the uplink planes
-    with cfg.aggregate_dtype before reducing, like both jnp paths do."""
+    with cfg.aggregate_dtype before reducing, like the tree oracle does."""
     cfg, eng, data, model = _setup("fedcm")
     cfg_bf = replace(cfg, aggregate_dtype="bfloat16")
     engs = {
-        "jnp_bf16": FederatedEngine(cfg_bf, eng.loss_fn, batch_size=8),
-        "kern_bf16": FederatedEngine(replace(cfg_bf, use_fused_kernel=True),
+        "tree_bf16": FederatedEngine(replace(cfg_bf, use_flat_plane=False),
                                      eng.loss_fn, batch_size=8),
-        "kern_f32": FederatedEngine(replace(cfg, use_fused_kernel=True),
-                                    eng.loss_fn, batch_size=8),
+        "kern_bf16": FederatedEngine(cfg_bf, eng.loss_fn, batch_size=8),
+        "kern_f32": eng,
     }
     out = {k: e.run_rounds(_fresh_state(e, model), data, 2)[0] for k, e in engs.items()}
-    # bf16 aggregation on the kernel path tracks the jnp bf16 path…
-    _assert_trees_close(out["kern_bf16"].params, out["jnp_bf16"].params,
+    # bf16 aggregation in the fold kernel tracks the tree oracle's…
+    _assert_trees_close(out["kern_bf16"].params, out["tree_bf16"].params,
                         rtol=2e-2, atol=2e-2)
     # …and actually differs from unquantized f32 aggregation
     diff = sum(
@@ -165,20 +164,15 @@ def test_fused_server_kernel_honors_aggregate_dtype():
     assert diff > 0.0
 
 
-def test_tree_path_ignores_fused_kernel_flag():
-    """The legacy whole-tree fedcm_update launch is RETIRED: on the tree
-    path ``use_fused_kernel`` is inert, so the trajectories must be
-    bitwise identical (any reappearing kernel route would show up as the
-    old tolerance-level drift)."""
-    cfg, eng, data, model = _setup("fedcm")
-    cfg_t = replace(cfg, use_flat_plane=False)
-    eng_t = FederatedEngine(cfg_t, eng.loss_fn, batch_size=8)
-    eng_tk = FederatedEngine(replace(cfg_t, use_fused_kernel=True), eng.loss_fn, batch_size=8)
-    s_ref, _ = eng_t.run_rounds(_fresh_state(eng_t, model), data, 3)
-    s_k, _ = eng_tk.run_rounds(_fresh_state(eng_tk, model), data, 3)
-    for a, b in zip(jax.tree_util.tree_leaves(s_ref.params),
-                    jax.tree_util.tree_leaves(s_k.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+@pytest.mark.parametrize("flat", [True, False], ids=["flat", "tree"])
+def test_engine_refuses_the_retired_jnp_path(flat):
+    """The flat engine has one path: ``use_fused_kernel=False`` is refused
+    at construction, on either representation, instead of running a
+    second engine the chip never measures."""
+    cfg, eng, _, _ = _setup("fedcm")
+    with pytest.raises(ValueError, match="one path"):
+        FederatedEngine(replace(cfg, use_fused_kernel=False, use_flat_plane=flat),
+                        eng.loss_fn, batch_size=8)
 
 
 def test_client_sharding_constraint_is_numerically_inert():
@@ -249,10 +243,10 @@ def test_async_depth1_is_exactly_run_rounds(algo):
 
 @pytest.mark.parametrize("algo", ["fedcm", "scaffold", "fedadam"])
 def test_async_depth1_kernel_path_is_exactly_run_rounds(algo):
-    """Same degenerate-schedule contract on the fused-kernel path (the
+    """Same degenerate-schedule contract over 3 rounds (the
     staleness-discount SMEM scalar is 1.0 there — must stay exact).
     fedadam covers a spec whose round-close is fold pass + pure post."""
-    cfg, eng, data, model = _setup(algo, use_fused_kernel=True)
+    cfg, eng, data, model = _setup(algo)
     s_sync, _ = eng.run_rounds(_fresh_state(eng, model), data, 3)
     s_async, _ = eng.run_rounds_async(
         _fresh_state(eng, model), data, 3, pipeline_depth=1, staleness=0
@@ -260,12 +254,16 @@ def test_async_depth1_kernel_path_is_exactly_run_rounds(algo):
     _assert_state_equal(s_sync, s_async)
 
 
-@pytest.mark.parametrize("use_fused_kernel", [False, True])
-def test_async_pipeline_fill_fold_drain(use_fused_kernel):
+@pytest.mark.parametrize("algo,shards", [
+    ("fedcm", 0), ("scaffold", 0), ("fedcm", 1), ("scaffold", 1),
+], ids=["fedcm", "scaffold", "fedcm-shard1", "scaffold-shard1"])
+def test_async_pipeline_fill_fold_drain(algo, shards):
     """D>1: the first D−1 rounds launch without folding (pipeline fill),
     every later round folds exactly one cohort, and the drain applies the
-    leftover in-flight work (drain=False must differ — work discarded)."""
-    cfg, eng, data, model = _setup("fedcm", use_fused_kernel=use_fused_kernel)
+    leftover in-flight work (drain=False must differ — work discarded).
+    scaffold folds the ring's client-state planes into the (N, P) scatter;
+    ``cohort_shard=1`` carries the sharded pass's padded ring entries."""
+    cfg, eng, data, model = _setup(algo, cohort_shard=shards)
     D = 3
     st, ms = eng.run_rounds_async(_fresh_state(eng, model), data, 6,
                                   pipeline_depth=D, staleness=0)
@@ -417,26 +415,29 @@ def test_async_staleness_converges_on_quadratic(depth, stale):
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("use_fused_kernel", [False, True])
-def test_bf16_run_round_matches_run_rounds_master_plane(use_fused_kernel):
+@pytest.mark.parametrize("algo", ["fedcm", "scaffold"])
+def test_bf16_run_round_matches_run_rounds_master_plane(algo):
     """Sequential run_round must continue the SAME f32 master planes the
     run_rounds scan carries (FedState.master), so their bf16 trajectories
     stay within an occasional single-ulp bf16 rounding flip of each other
     (f32-level noise pushed across a rounding boundary; ≤5e-4 here).  The
     legacy behaviour re-rounded the carried state to bf16 at EVERY
     run_round boundary — a ~4e-3 divergence that this bound would catch
-    coming back."""
-    cfg, eng, data, model = _setup("fedcm", use_fused_kernel=use_fused_kernel)
+    coming back.  scaffold adds the (N, P) master client-state plane."""
+    cfg, eng, data, model = _setup(algo)
     p_bf16 = tree_cast(model.init(jax.random.PRNGKey(0)), jnp.bfloat16)
 
     st = eng.init(p_bf16, jax.random.PRNGKey(1))
     assert st.master is not None  # sub-f32 leaves attach the master planes
+    assert (st.master.client_states is not None) == (algo == "scaffold")
     for _ in range(4):
         st, _ = eng.run_round(st, data)
     st_f, _ = eng.run_rounds(eng.init(p_bf16, jax.random.PRNGKey(1)), data, 4)
     assert st_f.master is not None
-    for a, b in zip(jax.tree_util.tree_leaves((st.params, st.server.momentum)),
-                    jax.tree_util.tree_leaves((st_f.params, st_f.server.momentum))):
+    for a, b in zip(jax.tree_util.tree_leaves((st.params, st.server.momentum,
+                                               st.client_states)),
+                    jax.tree_util.tree_leaves((st_f.params, st_f.server.momentum,
+                                               st_f.client_states))):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=0, atol=5e-4)

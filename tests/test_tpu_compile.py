@@ -156,7 +156,7 @@ def test_kernel_round_copies_no_plane(topo, monkeypatch, shards):
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
     model = build_model(TINY_LM)
     cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=shards, local_steps=2,
-                    participation="fixed", use_fused_kernel=True)
+                    participation="fixed")
     if shards > 1:
         mesh = device_mesh(topo.devices[:shards], ("clients",))
         where = NamedSharding(mesh, PartitionSpec())
@@ -209,7 +209,7 @@ def test_gradient_returns_to_the_plane_without_a_pad_per_leaf(one_chip, monkeypa
     monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
     model = mlp_classifier((8,) + (32,) * 21 + (4,))
     cfg = FedConfig(algo="fedcm", num_clients=4, cohort_size=2, local_steps=2,
-                    participation="fixed", use_fused_kernel=True, weight_decay=1e-3)
+                    participation="fixed", weight_decay=1e-3)
     eng = FederatedEngine(cfg, classification_loss(model.apply), batch_size=4)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     state = jax.eval_shape(eng.init, params, jax.random.PRNGKey(1))
